@@ -34,7 +34,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import evolve as ev
 from repro.core import fitness as fit
 from repro.core.islands import IslandConfig
@@ -1224,8 +1223,8 @@ def sharded_evolve_step(cfg: GPConfig, mesh, *, data_axis="data", model_axis="mo
     """
     step, state_specs, data_spec, y_spec, w_spec = _pick_step_builder(cfg)(
         cfg, mesh, data_axis=data_axis, model_axis=model_axis, pod_axis=pod_axis)
-    smapped = compat.shard_map(
-        step, mesh=mesh,
+    smapped = jax.shard_map(
+        step, mesh=mesh, check_vma=False,
         in_specs=(state_specs, data_spec, y_spec, w_spec),
         out_specs=state_specs,
     )
@@ -1276,8 +1275,8 @@ def sharded_evolve_block(cfg: GPConfig, mesh, *, n_steps: int, data_axis="data",
         return st, hist, counters
 
     hist_spec = P(None, pod_axis) if island else P()
-    smapped = compat.shard_map(
-        block, mesh=mesh,
+    smapped = jax.shard_map(
+        block, mesh=mesh, check_vma=False,
         in_specs=(state_specs, data_spec, y_spec, w_spec, P()),
         out_specs=(state_specs, hist_spec, P()),
     )
@@ -1361,8 +1360,8 @@ def build_stream_fold(cfg: GPConfig, mesh, *, data_axis: str = "data"):
                                         weight, data_axis, n_data)
         return kern.merge_moments(acc, merged, cfg.fitness)
 
-    smapped = compat.shard_map(
-        fold, mesh=mesh,
+    smapped = jax.shard_map(
+        fold, mesh=mesh, check_vma=False,
         in_specs=(P(), P(), P(), P(None, data_axis), P(data_axis),
                   P(data_axis)),
         out_specs=P(),

@@ -185,11 +185,33 @@ def resolve_dedup_cap(dedup_cap: int, pop: int, num_nodes: int) -> int:
     return int(min(cap, pop * num_nodes + 1))
 
 
+def _sorted_signatures(sigf):
+    """(order int32[T], is_new bool[T]) for the rows of sigf int32[T, W]:
+    `order` lists the rows lexicographically with ties in position order,
+    and `is_new[k]` marks the first of each run of equal rows in that
+    order. The order is built least-significant word first, one stable
+    single-key sort per word inside a loop — the permutation of a W-key
+    `lax.sort` with position as the last key, but a loop of one small
+    sort compiles in seconds for the TPU, where the W-key sort at depth
+    5 (W = 21) takes over ten minutes to compile."""
+    T, W = sigf.shape
+
+    def word(i, order):
+        key = sigf[order, W - 1 - i]
+        return jax.lax.sort((key, order), num_keys=1, is_stable=True)[1]
+
+    order = jax.lax.fori_loop(0, W, word, jnp.arange(T, dtype=jnp.int32))
+    srt = sigf[order]
+    is_new = jnp.concatenate([jnp.ones((1,), bool),
+                              (srt[1:] != srt[:-1]).any(axis=1)])
+    return order, is_new
+
+
 @partial(jax.jit, static_argnames=("spec", "cap"))
 def build_dedup_plan(op, arg, spec: TreeSpec, cap: int) -> DedupPlan:
     """Canonicalize + sort + unique the population's subtree spans into a
-    fixed-shape evaluation schedule. One variadic `lax.sort` over the
-    signature words (position as final tiebreak/payload) puts equal
+    fixed-shape evaluation schedule. A lexicographic sort of the
+    signature words (position as final tiebreak) puts equal
     subexpressions adjacent; segment heads become unique slots."""
     P, N = op.shape
     T = P * N
@@ -201,14 +223,7 @@ def build_dedup_plan(op, arg, spec: TreeSpec, cap: int) -> DedupPlan:
     length = jnp.arange(N, dtype=jnp.int32)[None, :] - start + 1
     lhs_i = trees_mod.postfix_lhs_index(op)
 
-    pos = jnp.arange(T, dtype=jnp.int32)
-    sorted_cols = jax.lax.sort(
-        tuple(sigf[:, k] for k in range(W)) + (pos,), num_keys=W + 1)
-    s_pos = sorted_cols[-1]
-    is_new = jnp.zeros((T,), bool).at[0].set(True)
-    for c in sorted_cols[:-1]:
-        is_new = is_new | jnp.concatenate(
-            [jnp.ones((1,), bool), c[1:] != c[:-1]])
+    s_pos, is_new = _sorted_signatures(sigf)
     new_u = is_new & active[s_pos]  # all-zero (inactive) sigs sort first
     uid_s = jnp.cumsum(new_u.astype(jnp.int32)) - 1
     n_unique = jnp.sum(new_u.astype(jnp.int32))
@@ -316,16 +331,9 @@ def dedup_stats(op, arg, spec: TreeSpec, cap: int):
     P, N = op.shape
     T = P * N
     sig = trees_mod.subtree_signatures(op, arg, spec).reshape(T, -1)
-    W = sig.shape[-1]
     active = (op != prim.EMPTY).reshape(T)
-    sorted_cols = jax.lax.sort(
-        tuple(sig[:, k] for k in range(W)) + (active.astype(jnp.int32),),
-        num_keys=W)
-    is_new = jnp.zeros((T,), bool).at[0].set(True)
-    for c in sorted_cols[:W]:
-        is_new = is_new | jnp.concatenate(
-            [jnp.ones((1,), bool), c[1:] != c[:-1]])
-    n_unique = jnp.sum((is_new & sorted_cols[-1].astype(bool)).astype(jnp.int32))
+    order, is_new = _sorted_signatures(sig)
+    n_unique = jnp.sum((is_new & active[order]).astype(jnp.int32))
     total = jnp.sum(active.astype(jnp.int32))
     saved = jnp.where(n_unique > cap - 1, 0, total - n_unique)
     return n_unique, saved

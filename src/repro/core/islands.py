@@ -44,7 +44,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.core.evolve import OperatorMix
 
 TOPOLOGIES = ("ring", "torus", "broadcast-best")
@@ -240,7 +239,7 @@ def migrate_sharded(icfg: IslandConfig, new_op, new_arg, elite_op, elite_arg,
     """
     k = icfg.migrate_k
     I_local = new_op.shape[0]
-    n_pods = compat.axis_size(pod_axis) if pod_axis else 1
+    n_pods = jax.lax.axis_size(pod_axis) if pod_axis else 1
     if k <= 0 or I_local * n_pods <= 1:
         return new_op, new_arg
     event_idx = generation // icfg.migrate_every
@@ -303,7 +302,7 @@ def migrate(cfg, op_local, arg_local, elite_op, elite_arg, generation,
     (`is_receiver`, one per pod) overwrites its last k offspring slots
     when a migration generation comes due.
     """
-    n_pods = compat.axis_size(pod_axis)
+    n_pods = jax.lax.axis_size(pod_axis)
     if n_pods <= 1:
         return op_local, arg_local
     k = cfg.migrate_k

@@ -48,7 +48,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.core import engine
 from repro.core import fitness as fit
 from repro.core import primitives as prim
@@ -387,7 +386,7 @@ class GPSession:
                 # warm_start refits reuse the jitted programs; rebuild only
                 # when the config or mesh actually changed
                 step, self._specs = self.build_sharded_step()
-                with compat.set_mesh(self.mesh):
+                with jax.set_mesh(self.mesh):
                     self._step_fn = jax.jit(step, donate_argnums=(0,))
                 self._block_cache = {}
                 self._built_for = (self._cfg, self.mesh)
@@ -542,7 +541,7 @@ class GPSession:
             # each chunk on the data axis)
             self.state = self._host_step(self.state)
         elif self._step_fn is not None:
-            with compat.set_mesh(self.mesh):
+            with jax.set_mesh(self.mesh):
                 self.state = self._step_fn(self.state, self._X, self._y,
                                            self._weight)
         elif self._backend.jittable:
@@ -591,10 +590,10 @@ class GPSession:
             block_fn = self._block_cache.get(n_steps)
             if block_fn is None:
                 block, _ = self.build_sharded_block(n_steps)
-                with compat.set_mesh(self.mesh):
+                with jax.set_mesh(self.mesh):
                     block_fn = jax.jit(block, donate_argnums=(0,))
                 self._block_cache[n_steps] = block_fn
-            with compat.set_mesh(self.mesh):
+            with jax.set_mesh(self.mesh):
                 self.state, history, counters = block_fn(
                     self.state, self._X, self._y, self._weight,
                     jnp.asarray(limit, jnp.int32))
@@ -683,7 +682,7 @@ class GPSession:
             sh_X = NamedSharding(self.mesh, P(None, "data"))
             sh_y = NamedSharding(self.mesh, P("data"))
             acc = jnp.zeros((op.shape[0], kern.n_moments), jnp.float32)
-            with compat.set_mesh(self.mesh):
+            with jax.set_mesh(self.mesh):
                 for X, y, w in self._stream:
                     # per-chunk host-side cost (place + dispatch; the fold
                     # itself is async) — no sync is added for timing

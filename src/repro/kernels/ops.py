@@ -1,8 +1,7 @@
 """Jitted public wrappers for the GP eval+fitness kernel.
 
 Handles padding (population to pop_tile, data to data_tile with a zero
-weight mask), picks the terminal-gather strategy, and sizes the data tile
-to a VMEM budget. Two surfaces:
+weight mask) and sizes the data tile to a VMEM budget. Two surfaces:
 
     fitness(...)  f32[P] finalized fitness — phase 1 moments accumulated
                   across the Pallas data grid, phase 2 reduce on the
@@ -24,7 +23,7 @@ from repro.core import eval as _eval
 from repro.core.fitness import FitnessSpec
 from repro.core.trees import TreeSpec
 from repro.kernels import ref as _ref
-from repro.kernels.gp_eval import (eval_fitness_pallas,
+from repro.kernels.gp_eval import (TABLE_PARTS, eval_fitness_pallas,
                                    eval_fitness_pallas_from_preds,
                                    eval_fitness_pallas_from_subtrees,
                                    eval_fitness_pallas_postfix)
@@ -32,75 +31,72 @@ from repro.kernels.gp_eval import (eval_fitness_pallas,
 _VMEM_BUDGET = 12 * 2**20  # bytes; leave headroom under ~16 MB/core
 
 
-def pick_tiles(n_features: int, n_nodes: int, pop: int, data: int,
-               pop_tile: int = 8, data_tile: int = 1024, gather: str | None = None):
-    """Choose (pop_tile, data_tile, gather) under the VMEM budget.
+def pick_tiles(n_terms: int, n_nodes: int, pop: int, data: int,
+               pop_tile: int = 8, data_tile: int = 1024):
+    """Choose (pop_tile, data_tile) for the tree kernel under the VMEM budget.
 
-    VMEM per block ≈ X tile + term/vals buffers (+ onehot when used):
-        X:      F · Db · 4
-        term:   Pb · N · Db · 4     (dominant)
-        vals:   ≤ Pb · (N+1) · Db · 4
-        onehot: Pb · N · F · 4
+    VMEM per block, with K = n_terms = features + constants (the rows of
+    gp_eval.terminal_table):
+        table:  2 buffers · 4 bf16 parts · K · Db · 2  = 16·K·Db
+        slots:  ≤ 4 · Pb · (N+1) · Db · 4  (the lookup's four products,
+                every slot's terminal value and the sweep's live values)
+        onehot: Pb · N · K · 2       (bf16, charged at 4 bytes)
+    Mosaic reports 29.1 MiB for F=9 at Db=4096 (modelled 33.1 MiB) and
+    21.6 MiB for F=1,373 at Db=1024 (modelled 32.2 MiB).
     """
-    if gather is None:
-        gather = "onehot" if n_features <= 64 else "vmem"
     Db = data_tile
 
     def vmem(Db):
-        base = 4 * (n_features * Db + 2 * pop_tile * (n_nodes + 1) * Db)
-        if gather == "onehot":
-            base += 4 * pop_tile * n_nodes * n_features
-        return base
+        return 4 * (TABLE_PARTS * n_terms * Db
+                    + 4 * pop_tile * (n_nodes + 1) * Db
+                    + pop_tile * n_nodes * n_terms)
 
     while Db > 128 and vmem(Db) > _VMEM_BUDGET:
         Db //= 2
-    return pop_tile, Db, gather
+    return pop_tile, Db
 
 
-def pick_tiles_postfix(n_features: int, stack_size: int, pop: int, data: int,
+def pick_tiles_postfix(n_terms: int, stack_size: int, pop: int, data: int,
                        pop_tile: int = 8, data_tile: int = 1024,
-                       gather: str | None = None, dedup_rows: int = 0):
-    """Tile pick for the postfix stack kernel. The carried state is a
-    [Pb, S, Db] stack (S = max_depth + 1), ~S/N of the tree kernel's
+                       dedup_rows: int = 0):
+    """Tile pick for the postfix stack kernel. The carried state is S
+    [Pb, Db] stack slots (S = max_depth + 1), ~S/N of the tree kernel's
     node-resident buffers, so the data tile can grow under the same VMEM
     budget — fewer, larger grid blocks amortize the per-instruction loop.
-    Gather defaults to "vmem": the stack kernel reads ONE terminal row
-    per instruction, where a dynamic take beats a one-hot matmul.
 
     `dedup_rows` (the dedup unique-table cap) charges the budget for the
-    f32[U, Db] unique-subtree scratch the in-VMEM dedup gather kernel
-    keeps resident per block. `_moments_padded` never lets this change
-    the picked tile — the dedup-off pick (``dedup_rows=0``) anchors the
-    merge order for the bitwise contract; the charged pick is the VMEM
-    re-check that decides whether the in-VMEM gather kernel is safe or
-    the gather must spill to HBM (`eval_fitness_pallas_from_preds`)."""
-    if gather is None:
-        gather = "vmem"
+    f32[U, Db] unique-subtree block the in-VMEM dedup gather kernel
+    keeps resident. `_moments_padded` never lets this change the picked
+    tile — the dedup-off pick (``dedup_rows=0``) anchors the merge order
+    for the bitwise contract; the charged pick is the VMEM re-check that
+    decides whether the in-VMEM gather kernel is safe or the gather must
+    spill to HBM (`eval_fitness_pallas_from_preds`)."""
     Db = data_tile
 
     def vmem(Db):
-        return _postfix_vmem(n_features, stack_size, pop_tile, Db, dedup_rows)
+        return _postfix_vmem(n_terms, stack_size, pop_tile, Db, dedup_rows)
 
     while Db * 2 <= data and vmem(Db * 2) <= _VMEM_BUDGET and Db < 2048:
         Db *= 2
     while Db > 128 and vmem(Db) > _VMEM_BUDGET:
         Db //= 2
-    return pop_tile, Db, gather
+    return pop_tile, Db
 
 
-def _postfix_vmem(n_features: int, stack_size: int, pop_tile: int, Db: int,
+def _postfix_vmem(n_terms: int, stack_size: int, pop_tile: int, Db: int,
                   dedup_rows: int = 0) -> int:
-    """VMEM bytes per block of the postfix stack kernel: X tile + stack
-    + the handful of [Pb, Db] per-instruction temps + the dedup
-    unique-subtree scratch when that kernel is live."""
-    return 4 * (n_features * Db + pop_tile * (stack_size + 8) * Db
-                + dedup_rows * Db)
+    """VMEM bytes per block of the postfix stack kernel: the double-
+    buffered split terminal table (16·K·Db) + the stack + the handful of
+    [Pb, Db] per-instruction temps + the double-buffered dedup
+    unique-subtree block when that kernel is live. Mosaic reports
+    21.8 MiB for F=1,373 at Db=1024 (modelled 22.0 MiB)."""
+    return 4 * (TABLE_PARTS * n_terms * Db + pop_tile * (stack_size + 8) * Db
+                + 2 * dedup_rows * Db)
 
 
 def _moments_padded(op, arg, X, y, const_table, tree_spec: TreeSpec,
                     fit_spec: FitnessSpec, weight, data_tile: int, pop_tile: int,
-                    gather: str | None, interpret: bool | None,
-                    dedup: str = "off", dedup_cap: int = 0):
+                    interpret: bool | None, dedup: str = "off", dedup_cap: int = 0):
     """Pad to tile multiples and run the fused kernel: f32[P, M] moments.
     Padded data points carry weight 0.0, so every moment they touch is an
     exact 0.0 and the grid accumulation stays padding-invariant.
@@ -119,20 +115,20 @@ def _moments_padded(op, arg, X, y, const_table, tree_spec: TreeSpec,
     `lax.cond`s back onto the plain kernel."""
     P, N = op.shape
     F, D = X.shape
+    K = F + const_table.shape[0]  # rows of the kernels' terminal table
     if tree_spec.genome == "postfix":
         cap = (_eval.resolve_dedup_cap(dedup_cap, P, N)
                if dedup != "off" else 0)
-        pop_tile, data_tile, gather = pick_tiles_postfix(
-            F, tree_spec.stack_size, P, D, pop_tile, data_tile, gather)
+        pop_tile, data_tile = pick_tiles_postfix(
+            K, tree_spec.stack_size, P, D, pop_tile, data_tile)
         # Would the f32[cap, Db] unique table still fit VMEM at this
         # exact pick?  If not, spill the gather to HBM instead of
         # shrinking the tile (which would change the merge order).
         dedup_fits = (cap == 0 or _postfix_vmem(
-            F, tree_spec.stack_size, pop_tile, data_tile,
+            K, tree_spec.stack_size, pop_tile, data_tile,
             dedup_rows=cap) <= _VMEM_BUDGET)
     else:
-        pop_tile, data_tile, gather = pick_tiles(F, N, P, D, pop_tile,
-                                                 data_tile, gather)
+        pop_tile, data_tile = pick_tiles(K, N, P, D, pop_tile, data_tile)
 
     pad_p = (-P) % pop_tile
     pad_d = (-D) % data_tile
@@ -162,7 +158,7 @@ def _moments_padded(op, arg, X, y, const_table, tree_spec: TreeSpec,
                 op_s, arg_s, lens[order], X, y, weight, const_table,
                 stack_size=tree_spec.stack_size, kernel=fit_spec.kernel,
                 n_classes=fit_spec.n_classes, precision=fit_spec.precision,
-                gather=gather, pop_tile=pop_tile, data_tile=data_tile,
+                pop_tile=pop_tile, data_tile=data_tile,
                 interpret=interpret, fn_codes=fn_codes)
             return out[jnp.argsort(order)]
 
@@ -189,18 +185,18 @@ def _moments_padded(op, arg, X, y, const_table, tree_spec: TreeSpec,
             return jax.lax.cond(plan.overflow, _plain, _dedup)[:P]
         return _plain()[:P]
     out = eval_fitness_pallas(
-        op, arg, X, y, weight, const_table, max_depth=tree_spec.max_depth,
-        kernel=fit_spec.kernel, n_classes=fit_spec.n_classes,
-        precision=fit_spec.precision, gather=gather, pop_tile=pop_tile,
-        data_tile=data_tile, interpret=interpret, fn_codes=fn_codes)
+        op, arg, X, y, weight, const_table, kernel=fit_spec.kernel,
+        n_classes=fit_spec.n_classes, precision=fit_spec.precision,
+        pop_tile=pop_tile, data_tile=data_tile, interpret=interpret,
+        fn_codes=fn_codes)
     return out[:P]
 
 
 @partial(jax.jit, static_argnames=("tree_spec", "fit_spec", "data_tile", "pop_tile",
-                                   "gather", "interpret", "dedup", "dedup_cap"))
+                                   "interpret", "dedup", "dedup_cap"))
 def moments(op, arg, X, y, const_table, tree_spec: TreeSpec, fit_spec: FitnessSpec,
             *, weight=None, data_tile: int = 1024, pop_tile: int = 8,
-            gather: str | None = None, interpret: bool | None = None,
+            interpret: bool | None = None,
             dedup: str = "off", dedup_cap: int = 0):
     """f32[P, M] phase-1 moments of every tree against (X:[F,D], y:[D]),
     fused with evaluation on the Pallas path. Sum with the other shards'
@@ -212,16 +208,16 @@ def moments(op, arg, X, y, const_table, tree_spec: TreeSpec, fit_spec: FitnessSp
         raise ValueError(f"fitness kernel {fit_spec.kernel!r} defines no moment "
                          f"pass; it cannot accumulate across data tiles/shards")
     return _moments_padded(op, arg, X, y, const_table, tree_spec, fit_spec,
-                           weight, data_tile, pop_tile, gather, interpret,
+                           weight, data_tile, pop_tile, interpret,
                            dedup=dedup, dedup_cap=dedup_cap)
 
 
 @partial(jax.jit, static_argnames=("tree_spec", "fit_spec", "data_tile", "pop_tile",
-                                   "gather", "impl", "interpret", "dedup",
+                                   "impl", "interpret", "dedup",
                                    "dedup_cap"))
 def stream_moments(acc, op, arg, X, y, const_table, tree_spec: TreeSpec,
                    fit_spec: FitnessSpec, *, weight=None, data_tile: int = 1024,
-                   pop_tile: int = 8, gather: str | None = None,
+                   pop_tile: int = 8,
                    impl: str = "pallas", interpret: bool | None = None,
                    dedup: str = "off", dedup_cap: int = 0):
     """One streaming fold step, ONE dispatch: phase-1 moments of this
@@ -243,17 +239,17 @@ def stream_moments(acc, op, arg, X, y, const_table, tree_spec: TreeSpec,
                                    dedup_cap=dedup_cap)
     else:
         m = _moments_padded(op, arg, X, y, const_table, tree_spec, fit_spec,
-                            weight, data_tile, pop_tile, gather, interpret,
+                            weight, data_tile, pop_tile, interpret,
                             dedup=dedup, dedup_cap=dedup_cap)
     return kern.merge_moments(acc, m, fit_spec)
 
 
 @partial(jax.jit, static_argnames=("tree_spec", "fit_spec", "data_tile", "pop_tile",
-                                   "gather", "impl", "interpret", "dedup",
+                                   "impl", "interpret", "dedup",
                                    "dedup_cap"))
 def fitness(op, arg, X, y, const_table, tree_spec: TreeSpec, fit_spec: FitnessSpec,
             *, weight=None, data_tile: int = 1024, pop_tile: int = 8,
-            gather: str | None = None, impl: str = "pallas",
+            impl: str = "pallas",
             interpret: bool | None = None,
             dedup: str = "off", dedup_cap: int = 0):
     """f32[P] fitness (minimize) of every tree against (X:[F,D], y:[D]).
@@ -272,6 +268,6 @@ def fitness(op, arg, X, y, const_table, tree_spec: TreeSpec, fit_spec: FitnessSp
         return _ref.fitness_ref(op, arg, X, y, const_table, tree_spec, fit_spec,
                                 weight=weight, dedup=dedup, dedup_cap=dedup_cap)
     m = _moments_padded(op, arg, X, y, const_table, tree_spec, fit_spec,
-                        weight, data_tile, pop_tile, gather, interpret,
+                        weight, data_tile, pop_tile, interpret,
                         dedup=dedup, dedup_cap=dedup_cap)
     return kern.reduce_moments(m, fit_spec)

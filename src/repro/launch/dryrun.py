@@ -1,4 +1,7 @@
 import os
+# compile-only tool: 512 fake CPU devices, pinned to the CPU even on a
+# machine whose default backend is a TPU
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=512"
 
 # Multi-pod dry-run: lower + compile every (architecture × shape × mesh)
@@ -25,7 +28,6 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs import all_arch_names, get_config
 from repro.launch.mesh import batch_axes, make_production_mesh
 from repro.launch import sharding as SH
@@ -108,7 +110,7 @@ def lower_cell(cfg, shape_name: str, mesh):
         state_sds = SH.named(mesh, state_specs, state_shapes)
         batch_sds = SH.named(mesh, SH.batch_specs(cfg, specs), specs)
         step = Md.make_train_step(cfg, opt, param_specs=state_specs["params"])
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             metric_shapes = jax.eval_shape(step, state_shapes, specs)[1]
             out_shardings = (
                 jax.tree.map(lambda s: SH.NamedSharding(mesh, s), state_specs),
@@ -131,7 +133,7 @@ def lower_cell(cfg, shape_name: str, mesh):
         def prefill_fn(p, b):
             return Md.prefill(cfg, p, b, max_len=S)
 
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             out_shardings = (SH.NamedSharding(mesh, logits_spec),
                              jax.tree.map(lambda s: SH.NamedSharding(mesh, s), cache_out))
             return jax.jit(prefill_fn, out_shardings=out_shardings).lower(
@@ -146,7 +148,7 @@ def lower_cell(cfg, shape_name: str, mesh):
                                           specs["token"]), specs["token"])
     len_sds = specs["cur_len"]
     step = Md.make_serve_step(cfg)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         # pinning cache out_shardings == in_shardings lets donation alias the
         # cache buffers (decode must be in-place at 100+ GB caches)
         long_logits = (SH.P(None, None, "model")
@@ -163,7 +165,7 @@ def analyze(lowered, *, want_hlo: bool = False) -> dict:
     compiled = lowered.compile()
     dt = time.time() - t0
     mem = compiled.memory_analysis()
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     coll = collective_bytes(hlo)
     rec = {
@@ -258,7 +260,7 @@ def run_gp_cell(name: str, multi_pod: bool, out_dir: str, keep_hlo: bool = False
     w_sds = SH.named(mesh, specs["weight"], sds((rows,), jnp.float32))
     limit_sds = SH.named(mesh, specs["limit"], sds((), jnp.int32))
     try:
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(block, donate_argnums=(0,)).lower(
                 state_sds, X_sds, y_sds, w_sds, limit_sds)
         rec = {"arch": name, "shape": f"pop{pop}_rows{rows}_F{F}_K{block_steps}",

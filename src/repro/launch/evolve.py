@@ -28,6 +28,7 @@ import numpy as np
 
 from repro.data.datasets import BY_NAME
 from repro.gp import GPSession, MeshTopology
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def parse_mesh(spec: str | None) -> MeshTopology | None:
@@ -42,7 +43,7 @@ def parse_mesh(spec: str | None) -> MeshTopology | None:
 
 
 def run_dataset(name: str, *, generations: int = 30, pop: int = 100,
-                depth: int = 5, backend: str = "jnp", fn_set: str = "auto",
+                depth: int = 5, backend: str = "auto", fn_set: str = "auto",
                 topology: MeshTopology | None = None,
                 archive: str | None = None, seed: int = 0, log=print,
                 ckpt_dir: str | None = None, ckpt_every: int = 10,
@@ -125,7 +126,7 @@ def main():
     ap.add_argument("--generations", type=int, default=30)
     ap.add_argument("--pop", type=int, default=100)
     ap.add_argument("--depth", type=int, default=5)
-    ap.add_argument("--backend", "--impl", dest="backend", default="jnp",
+    ap.add_argument("--backend", "--impl", dest="backend", default="auto",
                     help="eval backend: scalar | jnp | pallas | auto")
     ap.add_argument("--mesh", default=None,
                     help="mesh topology, e.g. data=2,model=2,pod=2")
@@ -165,6 +166,7 @@ def main():
                     help="which evolution block the profiler window wraps "
                          "(default 0)")
     args = ap.parse_args()
+    enable_compile_cache()
     run_dataset(args.dataset, generations=args.generations, pop=args.pop,
                 depth=args.depth, backend=args.backend,
                 topology=parse_mesh(args.mesh), archive=args.archive,

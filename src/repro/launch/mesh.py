@@ -11,21 +11,27 @@ Axes:
 """
 from __future__ import annotations
 
-from repro import compat
+import jax
+
+
+def _make_mesh(shape, axes):
+    """`jax.make_mesh` with every axis Auto (sharding propagated by XLA)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1, pod: int = 1):
     """Small mesh over however many (fake) devices the host exposes —
     used by integration tests."""
     if pod > 1:
-        return compat.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return compat.make_mesh((data, model), ("data", "model"))
+        return _make_mesh((pod, data, model), ("pod", "data", "model"))
+    return _make_mesh((data, model), ("data", "model"))
 
 
 def batch_axes(mesh) -> tuple:

@@ -26,6 +26,7 @@ import time
 import numpy as np
 
 from repro.data.datasets import BY_NAME
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.service import GPService, JobSpec
 
 
@@ -144,6 +145,7 @@ def main():
                     help="append metrics JSONL here (summarize with "
                          "python -m repro.obs.report)")
     args = ap.parse_args()
+    enable_compile_cache()
     jobs = (load_job_file(args.job_file, data_cap=args.data_cap)
             if args.job_file
             else synthetic_stream(args.jobs, seed=args.seed))

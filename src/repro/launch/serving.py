@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.models.layers import AttnDims, _qkv
 
 
@@ -54,7 +53,7 @@ def make_cp_decode_attention(dims: AttnDims, seq_axis: str = "data"):
     scale = 1.0 / math.sqrt(dims.d_head)
 
     def attend(p, x, cache_k, cache_v, cur_len):
-        nshard = compat.axis_size(seq_axis)
+        nshard = jax.lax.axis_size(seq_axis)
         rank = jax.lax.axis_index(seq_axis)
         S_loc = cache_k.shape[1]
         offset = rank * S_loc
@@ -95,9 +94,10 @@ def cp_decode_attention(p, x, cache_k, cache_v, cur_len, dims: AttnDims,
     cache_spec = P(b, seq_axis, None, None)
     xspec = P(b, None, None)
     pspec = jax.tree.map(lambda _: P(), p)
-    return compat.shard_map(
+    return jax.shard_map(
         attend,
         mesh=mesh,
+        check_vma=False,
         in_specs=(pspec, xspec, cache_spec, cache_spec, P()),
         out_specs=(xspec, cache_spec, cache_spec),
     )(p, x, cache_k, cache_v, cur_len)

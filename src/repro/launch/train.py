@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding
 
-from repro import compat
 from repro.ckpt.checkpoint import CheckpointManager
 from repro.configs import get_config, get_reduced
 from repro.data.loader import lm_batches
@@ -42,7 +41,7 @@ def build(cfg, mesh, seed: int = 0):
 
     state_shapes = jax.eval_shape(init_state, jax.random.PRNGKey(seed))
     specs = SH.train_state_specs(cfg, state_shapes, mesh)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = jax.jit(
             init_state,
             out_shardings=jax.tree.map(lambda s: NamedSharding(mesh, s), specs),
@@ -66,7 +65,7 @@ def train(cfg, *, steps: int, batch: int, seq: int, ckpt_dir: str | None = None,
     monitor = StepMonitor()
     stream = lm_batches(cfg.vocab, batch, seq)
     history = []
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         start = int(state["step"])
         for i, b in zip(range(start, steps), stream):
             with monitor:

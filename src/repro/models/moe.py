@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.models.layers import dense_init
 
 
@@ -166,8 +165,8 @@ def moe_apply_sharded(p, x, *, top_k: int, act: str = "silu",
     wspec = P(model_ax, fs, None)
     xspec = (P(batch_axes, model_ax, None) if seq_sharded
              else P(batch_axes, None, None))
-    out_y, aux = compat.shard_map(
-        block,
+    out_y, aux = jax.shard_map(
+        block, check_vma=False,
         in_specs=(xspec, P(None, None), wspec, wspec, wspec),
         out_specs=(xspec, P()),
     )(x, p["router"], _pad_e(p["w_up"], E_pad),

@@ -17,7 +17,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 
 
 def quantize(x):
@@ -38,7 +37,7 @@ def compressed_psum(grads, axis: str, residual=None):
     grads/residual: pytrees of f32 arrays (local gradient shards inside a
     shard_map body). Returns (mean_grads, new_residual).
     """
-    n = compat.axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if residual is None:
         residual = jax.tree.map(jnp.zeros_like, grads)
 

@@ -97,6 +97,7 @@ class GPService:
             self.tree_spec, self.kernels, tourn_draw, elitism, block_size,
             dedup=dedup, dedup_cap=dedup_cap),
             donate_argnums=(0,))
+        self._exe = None  # the block compiled ahead of time (_compiled_block)
         self._state = engine.empty_tenant_state(slots, pop_size, self.tree_spec,
                                                 elitism=elitism)
         self._gens = np.zeros((slots,), np.int64)  # host mirror of gens_done
@@ -204,6 +205,10 @@ class GPService:
         # process resuming someone else's checkpoints)
         from repro.ckpt.checkpoint import latest_step
 
+        # compile outside the restart policy: a program the compiler
+        # refuses fails the same way on every replay, so it must raise
+        # on the first attempt instead of burning the restart budget
+        self._compiled_block(*self.batch.operands())
         latest = latest_step(self._manager.directory)
         if latest is None or latest < self._ckpt_step:
             self._live_snap = self._make_snapshot()
@@ -265,17 +270,15 @@ class GPService:
 
     def _dispatch_and_publish(self):
         X, y, w, params = self.batch.operands()
+        block = self._compiled_block(X, y, w, params)
         with self._block_monitor, self.tracer.span(
                 "dispatch", args={"occupied": len(self.batch.occupied)}):
-            self._state, hist, counters = self._block(self._state, X, y, w,
-                                                      params)
+            self._state, hist, counters = block(self._state, X, y, w, params)
             # ONE host sync per block: counters, champions and the
             # per-generation streams come back together
             host, hist, crows = jax.device_get((self._state, hist, counters))
         hist = np.asarray(hist)  # [K, I]
         self._absorb_counters(crows)
-        self.stats["compiles"] = self._compile_count()
-        self.metrics.gauge("compiles", self.stats["compiles"])
 
         budgets = np.asarray(params.budget)
         stops = np.asarray(params.stop)
@@ -342,15 +345,18 @@ class GPService:
     def _worker_id(self, handle: JobHandle) -> str:
         return f"job-{handle.job_id}"
 
-    def _compile_count(self) -> int:
-        """How many programs the tenant block compiled — the service's
-        no-recompile guarantee pins this at 1 across every admission/
-        eviction. Falls back to the blocks counter's floor if the jax
-        version hides the cache."""
-        try:
-            return int(self._block._cache_size())
-        except AttributeError:
-            return 1 if self.stats["blocks"] else 0
+    def _compiled_block(self, X, y, w, params):
+        """The tenant block compiled ahead of time, once. Admission and
+        eviction only rebind operands of the fixed layout, so one
+        executable serves every block — `stats["compiles"]` pins the
+        no-recompile guarantee, and an operand whose shape drifted is a
+        TypeError here rather than a silent recompile."""
+        if self._exe is None:
+            self._exe = self._block.lower(self._state, X, y, w,
+                                          params).compile()
+            self.stats["compiles"] += 1
+            self.metrics.gauge("compiles", self.stats["compiles"])
+        return self._exe
 
     # --- checkpoint payload ---------------------------------------------------
 

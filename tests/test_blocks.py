@@ -240,7 +240,6 @@ _SUBPROCESS_MESH_BLOCKS = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
-    from repro import compat
     from repro.core import (GPConfig, TreeSpec, FitnessSpec, init_state,
                             sharded_evolve_step, sharded_evolve_block)
     from repro.core.engine import evolve_step
@@ -260,7 +259,7 @@ _SUBPROCESS_MESH_BLOCKS = textwrap.dedent("""
     step, _ = sharded_evolve_step(cfg, mesh, pod_axis="pod")
     block, _ = sharded_evolve_block(cfg, mesh, n_steps=6, pod_axis="pod")
     s_step = init_state(cfg, jax.random.PRNGKey(0))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         js = jax.jit(step)
         for _ in range(6):
             s_step = js(s_step, X, y, w)

@@ -70,7 +70,6 @@ _ELASTIC = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from repro.configs import get_reduced
     from repro.launch.mesh import make_host_mesh, batch_axes
-    from repro import compat
     from repro.launch import sharding as SH
     from repro.models import model as Md
     from repro.models.transformer import ShardingPolicy
@@ -99,7 +98,7 @@ _ELASTIC = textwrap.dedent("""
         step = jax.jit(Md.make_train_step(cfg_b, opt, param_specs=specs["params"]))
         toks = jnp.zeros((4, 16), jnp.int32)
         batch = {"tokens": toks, "labels": toks, "mask": jnp.ones((4,16), jnp.float32)}
-        with compat.set_mesh(mesh_b):
+        with jax.set_mesh(mesh_b):
             state_b2, m = step(state_b, batch)
         assert np.isfinite(float(m["loss"]))
     print("ELASTIC_OK")
@@ -121,7 +120,6 @@ _ELASTIC_GP = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro import compat
     from repro.core import engine
     from repro.ckpt.checkpoint import save, restore
     from repro.ckpt.elastic import reshard_gp_state
@@ -158,7 +156,7 @@ _ELASTIC_GP = textwrap.dedent("""
         Xd = jax.device_put(jnp.asarray(X_fm), NamedSharding(mesh_b, P(None, "data")))
         yd = jax.device_put(jnp.asarray(yy), NamedSharding(mesh_b, P("data")))
         wd = jax.device_put(jnp.asarray(w), NamedSharding(mesh_b, P("data")))
-        with compat.set_mesh(mesh_b):
+        with jax.set_mesh(mesh_b):
             state_b2 = jax.jit(step)(state_b, Xd, yd, wd)
         assert int(jnp.max(state_b2.generation)) == int(np.max(host.generation)) + 1
         assert float(jnp.min(state_b2.best_fitness)) <= float(np.min(host.best_fitness))

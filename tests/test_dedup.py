@@ -205,6 +205,20 @@ def test_dedup_scatter_reconstruction_property(seed, depth, pop, cap):
     np.testing.assert_array_equal(base, out)
 
 
+def test_sorted_signatures_is_lexicographic_with_position_ties():
+    """The looped least-significant-word sort must give exactly the order
+    of a lexicographic sort with position as the final key, and flag the
+    head of every run of equal rows."""
+    r = np.random.RandomState(0)
+    sig = r.randint(0, 3, size=(257, 5)).astype(np.int32)  # many duplicates
+    order, is_new = ce._sorted_signatures(jnp.asarray(sig))
+    want = np.lexsort(tuple(sig[:, k] for k in range(4, -1, -1)))
+    np.testing.assert_array_equal(np.asarray(order), want)
+    srt = sig[want]
+    heads = np.concatenate([[True], (srt[1:] != srt[:-1]).any(axis=1)])
+    np.testing.assert_array_equal(np.asarray(is_new), heads)
+
+
 def test_resolve_dedup_cap():
     assert ce.resolve_dedup_cap(512, 1024, 63) == 512
     assert ce.resolve_dedup_cap(0, 1024, 63) == 1024
@@ -244,7 +258,7 @@ def test_fitness_dedup_parity_bitwise(kernel, impl, cap):
     X, y = _data(7, 4, 777)
     fs = FitnessSpec(kernel)
     ct = spec_p.const_table()
-    kw = dict(impl=impl, gather="vmem", data_tile=512, pop_tile=8)
+    kw = dict(impl=impl, data_tile=512, pop_tile=8)
     f0 = np.asarray(kops.fitness(op, arg, X, y, ct, spec_p, fs, **kw))
     f1 = np.asarray(kops.fitness(op, arg, X, y, ct, spec_p, fs,
                                  dedup="exact", dedup_cap=cap, **kw))
@@ -291,11 +305,10 @@ def test_pick_tiles_postfix_accounts_dedup_scratch():
     again = kops.pick_tiles_postfix(4, 6, 1024, 1 << 20, pop_tile=8,
                                     data_tile=65536, dedup_rows=0)
     assert base == again
-    pt, dt, gather = kops.pick_tiles_postfix(4, 6, 1024, 1 << 20, pop_tile=8,
-                                             data_tile=65536,
-                                             dedup_rows=100_000)
+    pt, dt = kops.pick_tiles_postfix(4, 6, 1024, 1 << 20, pop_tile=8,
+                                     data_tile=65536, dedup_rows=100_000)
     assert dt < base[1]  # the scratch is charged against the budget
-    vmem = 4 * (4 * dt + pt * (6 + 8) * dt + 100_000 * dt)
+    vmem = 4 * (4 * 4 * dt + pt * (6 + 8) * dt + 2 * 100_000 * dt)
     assert vmem <= kops._VMEM_BUDGET or dt == 128  # floor tile is the stop
 
 
@@ -516,7 +529,6 @@ _SUBPROCESS_MESH_DEDUP = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses
     import jax, jax.numpy as jnp, numpy as np
-    from repro import compat
     from repro.core import (GPConfig, TreeSpec, FitnessSpec, init_state,
                             sharded_evolve_block)
     from repro.core.islands import IslandConfig
@@ -539,7 +551,7 @@ _SUBPROCESS_MESH_DEDUP = textwrap.dedent("""
             cfg = GPConfig(dedup=mode, dedup_cap=100_000, **base)
             block, _ = sharded_evolve_block(cfg, mesh, n_steps=5,
                                             pod_axis="pod")
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 s, hist, ctr = jax.jit(block)(
                     init_state(cfg, jax.random.PRNGKey(0)), X, y, w,
                     jnp.asarray(5, jnp.int32))

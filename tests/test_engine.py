@@ -55,7 +55,6 @@ _SUBPROCESS_SHARDED = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from repro.core import GPConfig, TreeSpec, FitnessSpec, init_state, sharded_evolve_step, evolve_step
     from repro.launch.mesh import make_host_mesh
-    from repro import compat
 
     spec = TreeSpec(max_depth=5, n_features=2, n_consts=8)
     cfg = GPConfig(pop_size=64, tree_spec=spec, fitness=FitnessSpec("r"),
@@ -69,7 +68,7 @@ _SUBPROCESS_SHARDED = textwrap.dedent("""
     mesh = make_host_mesh(data=2, model=2, pod=2)
     step, specs = sharded_evolve_step(cfg, mesh, pod_axis="pod")
     s = init_state(cfg, jax.random.PRNGKey(0))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         js = jax.jit(step)
         for _ in range(12):
             s = js(s, jnp.asarray(Xk), jnp.asarray(yk), wk)
@@ -81,7 +80,7 @@ _SUBPROCESS_SHARDED = textwrap.dedent("""
     mesh2 = make_host_mesh(data=4, model=2)
     step2, _ = sharded_evolve_step(cfg, mesh2)
     s2 = init_state(cfg, jax.random.PRNGKey(0))
-    with compat.set_mesh(mesh2):
+    with jax.set_mesh(mesh2):
         js2 = jax.jit(step2)
         for _ in range(12):
             s2 = js2(s2, jnp.asarray(Xk), jnp.asarray(yk), wk)

@@ -9,7 +9,6 @@ import pytest
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 from benchmarks.hlo_cost import HloAnalyzer, analyze_hlo_text  # noqa: E402
-from repro import compat  # noqa: E402
 
 
 def _compile(f, *shapes):
@@ -23,7 +22,7 @@ def test_matches_xla_loop_free():
     c = _compile(f, jax.ShapeDtypeStruct((64, 128), jnp.float32),
                  jax.ShapeDtypeStruct((128, 128), jnp.float32))
     got = analyze_hlo_text(c.as_text())
-    want = compat.cost_analysis(c)["flops"]
+    want = c.cost_analysis()["flops"]
     assert abs(got["flops"] - want) / want < 0.05
 
 
@@ -36,7 +35,7 @@ def test_scan_multiplied_by_trip_count():
     expect = 10 * 2 * 256**3
     assert abs(got["flops"] - expect) / expect < 0.05
     # and the built-in analysis indeed undercounts (the reason we exist)
-    assert compat.cost_analysis(c)["flops"] < expect / 5
+    assert c.cost_analysis()["flops"] < expect / 5
 
 
 def test_nested_scans_compose():

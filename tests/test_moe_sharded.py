@@ -13,7 +13,6 @@ _SCRIPT = textwrap.dedent("""
     from repro.models import moe as M
     from repro.models.transformer import ShardingPolicy
     from repro.launch.mesh import make_host_mesh
-    from repro import compat
 
     mesh = make_host_mesh(data=2, model=4)
     pol = ShardingPolicy(batch=("data",), model="model", tp_size=4, dp_size=2)
@@ -24,7 +23,7 @@ _SCRIPT = textwrap.dedent("""
     # divisible experts
     p = M.moe_init(jax.random.PRNGKey(0), d, ff, E)
     y_ref, _ = M.moe_apply(p, x, top_k=2, capacity_factor=8.0)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         y_sh, _ = jax.jit(lambda p, x: M.moe_apply_sharded(
             p, x, top_k=2, capacity_factor=8.0, policy=pol))(p, x)
     np.testing.assert_allclose(np.asarray(y_sh), np.asarray(y_ref),
@@ -33,14 +32,14 @@ _SCRIPT = textwrap.dedent("""
     # non-divisible experts (granite case): 5 -> padded to 8
     p5 = M.moe_init(jax.random.PRNGKey(1), d, ff, 5)
     y5_ref, _ = M.moe_apply(p5, x, top_k=2, capacity_factor=8.0)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         y5_sh, _ = jax.jit(lambda p, x: M.moe_apply_sharded(
             p, x, top_k=2, capacity_factor=8.0, policy=pol))(p5, x)
     np.testing.assert_allclose(np.asarray(y5_sh), np.asarray(y5_ref),
                                rtol=2e-5, atol=2e-5)
 
     # gradients through shard_map + all_to_all + remat
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         g = jax.jit(jax.grad(lambda p, x: M.moe_apply_sharded(
             p, x, top_k=2, policy=pol)[0].astype(jnp.float32).sum()))(p, x)
     assert all(np.isfinite(np.asarray(l)).all() for l in jax.tree.leaves(g))

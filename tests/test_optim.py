@@ -64,7 +64,6 @@ _COMPRESS_SUBPROCESS = textwrap.dedent("""
     from jax.sharding import PartitionSpec as P
     from repro.optim.compress import compressed_psum
     from repro.launch.mesh import make_host_mesh
-    from repro import compat
 
     mesh = make_host_mesh(data=4, model=1)
     rng = np.random.RandomState(0)
@@ -74,9 +73,9 @@ _COMPRESS_SUBPROCESS = textwrap.dedent("""
         mean, new_r = compressed_psum({"g": g}, "data", {"g": r})
         return mean["g"], new_r["g"]
 
-    f = jax.jit(compat.shard_map(body, mesh=mesh, in_specs=(P("data"), P("data")),
+    f = jax.jit(jax.shard_map(body, mesh=mesh, check_vma=False, in_specs=(P("data"), P("data")),
                                   out_specs=(P("data"), P("data"))))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         resid = jnp.zeros((4*128 // 4 * 4,), jnp.float32).reshape(512)[:512]*0
         resid = jnp.zeros((512,), jnp.float32)
         g = jnp.asarray(gs.reshape(512))

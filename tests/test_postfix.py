@@ -84,7 +84,7 @@ def test_fitness_parity_tree_vs_postfix_bitwise(kernel, impl):
     X, y = _data(7, 4, 777)
     fs = FitnessSpec(kernel)
     ct = spec_t.const_table()
-    kw = dict(impl=impl, gather="vmem", data_tile=512, pop_tile=8)
+    kw = dict(impl=impl, data_tile=512, pop_tile=8)
     f_t = np.asarray(kops.fitness(op_t, arg_t, X, y, ct, spec_t, fs, **kw))
     f_p = np.asarray(kops.fitness(op_p, arg_p, X, y, ct, spec_p, fs, **kw))
     np.testing.assert_array_equal(f_t, f_p)

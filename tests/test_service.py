@@ -183,6 +183,24 @@ def test_restart_replays_to_identical_results(tmp_path):
         assert h.best_expression == r.best_expression
 
 
+def test_compile_error_raises_without_restarts(tmp_path):
+    """A block the compiler refuses fails identically on every replay, so
+    the restart policy must not retry it: it raises on the first attempt."""
+    attempts = []
+
+    class Refused:
+        def lower(self, *args):
+            attempts.append(1)
+            raise RuntimeError("compiler refused the tenant block")
+
+    svc = _service(checkpoint_dir=str(tmp_path), checkpoint_every=1)
+    svc.submit(_spec(0, 16, generations=4))
+    svc._block = Refused()
+    with pytest.raises(RuntimeError, match="compiler refused"):
+        svc.run()
+    assert attempts == [1] and svc.stats["restarts"] == 0
+
+
 # --- slot invariance & elastic resume --------------------------------------------
 
 

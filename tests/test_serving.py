@@ -11,7 +11,6 @@ _SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from repro.launch.mesh import make_host_mesh
-    from repro import compat
     from repro.launch.serving import cp_decode_attention
     from repro.models.layers import AttnDims, attn_decode, attn_init
 
@@ -27,7 +26,7 @@ _SCRIPT = textwrap.dedent("""
         x = jnp.asarray(rng.randn(B, 1, 32).astype(np.float32) * 0.3)
         want_o, want_k, want_v = attn_decode(p, x, ck, cv,
                                              jnp.asarray(cur_len), dims)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             got_o, got_k, got_v = jax.jit(
                 lambda p, x, ck, cv, L: cp_decode_attention(
                     p, x, ck, cv, L, dims, mesh, seq_axis="data"))(
