@@ -38,6 +38,7 @@ from repro.core import evolve as ev
 from repro.core import fitness as fit
 from repro.core.islands import IslandConfig
 from repro.core.trees import TreeSpec, generate_population
+from repro.obs.trace import span as host_span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -236,7 +237,8 @@ def init_state(cfg: GPConfig, key, seeds=None, feature_names=None) -> GPState:
     N = cfg.tree_spec.num_nodes
     E = cache_width(cfg)
     if I == 1:
-        op, arg = one_island(k1)
+        with host_span("fit.init_population"):
+            op, arg = one_island(k1)
         return GPState(
             key=k0, op=op, arg=arg,
             fitness=jnp.full((cfg.pop_size,), jnp.inf, jnp.float32),
@@ -250,7 +252,8 @@ def init_state(cfg: GPConfig, key, seeds=None, feature_names=None) -> GPState:
     if cfg.island.migrate_k > cfg.pop_size:
         raise ValueError(f"migrate_k {cfg.island.migrate_k} exceeds the "
                          f"per-island pop_size {cfg.pop_size}")
-    pairs = [one_island(jax.random.fold_in(k1, i)) for i in range(I)]
+    with host_span("fit.init_population"):
+        pairs = [one_island(jax.random.fold_in(k1, i)) for i in range(I)]
     keys = jnp.stack([jax.random.fold_in(k0, i) for i in range(I)])
     return GPState(
         key=keys,
@@ -359,31 +362,36 @@ def _step_body(cfg: GPConfig, state: GPState, X, y, weight) -> GPState:
     (`evolve_step`) and the scanned block (`evolve_block`), so K scanned
     steps are bitwise-identical to K dispatched steps."""
     const_table = cfg.tree_spec.const_table()
-    fitness = _cached_fitness(
-        state, lambda o, a: _eval_fitness(cfg, o, a, X, y, weight, const_table),
-        probe=_probe_fn(cfg, X, const_table))
-    # best tracked on RAW fitness; selection may add parsimony pressure
-    i = jnp.argmin(fitness)
-    improved = fitness[i] < state.best_fitness
-    best_op = jnp.where(improved, state.op[i], state.best_op)
-    best_arg = jnp.where(improved, state.arg[i], state.best_arg)
-    best_fit = jnp.minimum(fitness[i], state.best_fitness)
+    with jax.named_scope("gp.eval"):
+        fitness = _cached_fitness(
+            state,
+            lambda o, a: _eval_fitness(cfg, o, a, X, y, weight, const_table),
+            probe=_probe_fn(cfg, X, const_table))
+    with jax.named_scope("gp.select_best"):
+        # best tracked on RAW fitness; selection may add parsimony pressure
+        i = jnp.argmin(fitness)
+        improved = fitness[i] < state.best_fitness
+        best_op = jnp.where(improved, state.op[i], state.best_op)
+        best_arg = jnp.where(improved, state.arg[i], state.best_arg)
+        best_fit = jnp.minimum(fitness[i], state.best_fitness)
 
-    sel_fitness = fitness
-    if cfg.parsimony:
-        from repro.core.trees import tree_sizes
+        sel_fitness = fitness
+        if cfg.parsimony:
+            from repro.core.trees import tree_sizes
 
-        sel_fitness = fitness + cfg.parsimony * tree_sizes(state.op).astype(jnp.float32)
+            sel_fitness = fitness + cfg.parsimony * tree_sizes(
+                state.op).astype(jnp.float32)
 
-    E = state.cache_op.shape[0]
-    cache_op, cache_arg, cache_fit = (
-        _new_cache(state, fitness, sel_fitness, E) if E
-        else (state.cache_op, state.cache_arg, state.cache_fit))
+        E = state.cache_op.shape[0]
+        cache_op, cache_arg, cache_fit = (
+            _new_cache(state, fitness, sel_fitness, E) if E
+            else (state.cache_op, state.cache_arg, state.cache_fit))
 
-    key, k_next = jax.random.split(state.key)
-    new_op, new_arg = ev.next_generation(
-        k_next, state.op, state.arg, sel_fitness, cfg.tree_spec, cfg.mix,
-        cfg.tourn_size, cfg.elitism)
+    with jax.named_scope("gp.breed"):
+        key, k_next = jax.random.split(state.key)
+        new_op, new_arg = ev.next_generation(
+            k_next, state.op, state.arg, sel_fitness, cfg.tree_spec, cfg.mix,
+            cfg.tourn_size, cfg.elitism)
     return GPState(key, new_op, new_arg, fitness, best_op, best_arg, best_fit,
                    state.generation + 1, cache_op, cache_arg, cache_fit)
 
@@ -418,59 +426,65 @@ def _island_step_body(cfg: GPConfig, state: GPState, X, y, weight) -> GPState:
                              X, y, weight, const_table).reshape(I, R)
 
     E = state.cache_op.shape[1]
-    if E:
-        # one hit predicate for ALL islands: a per-island cond would lower
-        # to a select that evaluates both branches anyway. From gen 2 every
-        # island hits every generation (migration only writes the last
-        # migrate_k slots), so the all-or-nothing gate costs nothing.
-        hit = (jnp.all(state.op[:, :E] == state.cache_op)
-               & jnp.all(state.arg[:, :E] == state.cache_arg))
-        probe = _probe_fn(cfg, X, const_table)
-        if probe is not None:
-            hit = hit | _semantic_hit(
-                (state.op[:, :E], state.arg[:, :E]),
-                (state.cache_op, state.cache_arg), state.cache_fit, probe)
-        tail = eval_rows(state.op[:, E:], state.arg[:, E:])
-        head = jax.lax.cond(
-            hit, lambda: state.cache_fit,
-            lambda: eval_rows(state.op[:, :E], state.arg[:, :E]))
-        fitness = jnp.concatenate([head, tail], axis=1)
-    else:
-        fitness = eval_rows(state.op, state.arg)
+    with jax.named_scope("gp.eval"):
+        if E:
+            # one hit predicate for ALL islands: a per-island cond would
+            # lower to a select that evaluates both branches anyway. From
+            # gen 2 every island hits every generation (migration only
+            # writes the last migrate_k slots), so the all-or-nothing gate
+            # costs nothing.
+            hit = (jnp.all(state.op[:, :E] == state.cache_op)
+                   & jnp.all(state.arg[:, :E] == state.cache_arg))
+            probe = _probe_fn(cfg, X, const_table)
+            if probe is not None:
+                hit = hit | _semantic_hit(
+                    (state.op[:, :E], state.arg[:, :E]),
+                    (state.cache_op, state.cache_arg), state.cache_fit, probe)
+            tail = eval_rows(state.op[:, E:], state.arg[:, E:])
+            head = jax.lax.cond(
+                hit, lambda: state.cache_fit,
+                lambda: eval_rows(state.op[:, :E], state.arg[:, :E]))
+            fitness = jnp.concatenate([head, tail], axis=1)
+        else:
+            fitness = eval_rows(state.op, state.arg)
 
-    # per-island champion tracking on RAW fitness
-    i_best = jnp.argmin(fitness, axis=1)  # [I]
-    rows = jnp.arange(I)
-    cand_fit = fitness[rows, i_best]
-    cand_op = state.op[rows, i_best]  # [I, N]
-    cand_arg = state.arg[rows, i_best]
-    improved = cand_fit < state.best_fitness
-    best_op = jnp.where(improved[:, None], cand_op, state.best_op)
-    best_arg = jnp.where(improved[:, None], cand_arg, state.best_arg)
-    best_fit = jnp.minimum(cand_fit, state.best_fitness)
+    with jax.named_scope("gp.select_best"):
+        # per-island champion tracking on RAW fitness
+        i_best = jnp.argmin(fitness, axis=1)  # [I]
+        rows = jnp.arange(I)
+        cand_fit = fitness[rows, i_best]
+        cand_op = state.op[rows, i_best]  # [I, N]
+        cand_arg = state.arg[rows, i_best]
+        improved = cand_fit < state.best_fitness
+        best_op = jnp.where(improved[:, None], cand_op, state.best_op)
+        best_arg = jnp.where(improved[:, None], cand_arg, state.best_arg)
+        best_fit = jnp.minimum(cand_fit, state.best_fitness)
 
-    sel_fitness = fitness
-    if cfg.parsimony:
-        from repro.core.trees import tree_sizes
+        sel_fitness = fitness
+        if cfg.parsimony:
+            from repro.core.trees import tree_sizes
 
-        sizes = tree_sizes(state.op.reshape(I * P, N)).reshape(I, P)
-        sel_fitness = fitness + cfg.parsimony * sizes.astype(jnp.float32)
+            sizes = tree_sizes(state.op.reshape(I * P, N)).reshape(I, P)
+            sel_fitness = fitness + cfg.parsimony * sizes.astype(jnp.float32)
 
-    cache_op, cache_arg, cache_fit = (
-        _new_cache(state, fitness, sel_fitness, E) if E
-        else (state.cache_op, state.cache_arg, state.cache_fit))
+        cache_op, cache_arg, cache_fit = (
+            _new_cache(state, fitness, sel_fitness, E) if E
+            else (state.cache_op, state.cache_arg, state.cache_fit))
 
-    probs, tourn_max, tourn, p_point = _island_tables(cfg)
-    breed = ev.make_island_breeder(cfg.tree_spec, tourn_max, cfg.elitism)
-    keys, new_op, new_arg = jax.vmap(breed)(
-        state.key, state.op, state.arg, sel_fitness, jnp.asarray(probs),
-        jnp.asarray(tourn), jnp.asarray(p_point))
+    with jax.named_scope("gp.breed"):
+        probs, tourn_max, tourn, p_point = _island_tables(cfg)
+        breed = ev.make_island_breeder(cfg.tree_spec, tourn_max, cfg.elitism)
+        keys, new_op, new_arg = jax.vmap(breed)(
+            state.key, state.op, state.arg, sel_fitness, jnp.asarray(probs),
+            jnp.asarray(tourn), jnp.asarray(p_point))
 
     if icfg.migrate_k and I > 1:
-        e_op, e_arg = isl.island_elites(state.op, state.arg, fitness,
-                                        icfg.migrate_k)
-        new_op, new_arg = isl.migrate_local(icfg, new_op, new_arg, e_op, e_arg,
-                                            state.generation, cand_fit)
+        with jax.named_scope("gp.migrate"):
+            e_op, e_arg = isl.island_elites(state.op, state.arg, fitness,
+                                            icfg.migrate_k)
+            new_op, new_arg = isl.migrate_local(icfg, new_op, new_arg, e_op,
+                                                e_arg, state.generation,
+                                                cand_fit)
     return GPState(keys, new_op, new_arg, fitness, best_op, best_arg, best_fit,
                    state.generation + 1, cache_op, cache_arg, cache_fit)
 
@@ -613,7 +627,8 @@ def evolve_block(cfg: GPConfig, state: GPState, X, y, weight=None, limit=None, *
     def body(s, i):
         nxt = _step_body_any(cfg, s, X, y, weight)
         done = _block_done(cfg, s, i, limit)
-        row = _counter_row(cfg, s, done if can_freeze else None)
+        with jax.named_scope("gp.telemetry"):
+            row = _counter_row(cfg, s, done if can_freeze else None)
         if can_freeze:
             nxt = _freeze(done, s, nxt)
         return nxt, (nxt.best_fitness, row)
@@ -811,34 +826,37 @@ def _tenant_slot_step(spec: TreeSpec, kernels: tuple, tourn_draw: int,
                                p.n_classes, p.precision)
 
     E = sub.cache_op.shape[0]
-    if E:
-        hit = (jnp.all(sub.op[:E] == sub.cache_op)
-               & jnp.all(sub.arg[:E] == sub.cache_arg))
-        tail = eval_rows(sub.op[E:], sub.arg[E:])
-        head = jax.lax.cond(hit, lambda: sub.cache_fit,
-                            lambda: eval_rows(sub.op[:E], sub.arg[:E]))
-        fitness = jnp.concatenate([head, tail])
-    else:
-        fitness = eval_rows(sub.op, sub.arg)
-    i = jnp.argmin(fitness)
-    improved = fitness[i] < sub.best_fitness
-    best_op = jnp.where(improved, sub.op[i], sub.best_op)
-    best_arg = jnp.where(improved, sub.arg[i], sub.best_arg)
-    best_fit = jnp.minimum(fitness[i], sub.best_fitness)
+    with jax.named_scope("gp.eval"):
+        if E:
+            hit = (jnp.all(sub.op[:E] == sub.cache_op)
+                   & jnp.all(sub.arg[:E] == sub.cache_arg))
+            tail = eval_rows(sub.op[E:], sub.arg[E:])
+            head = jax.lax.cond(hit, lambda: sub.cache_fit,
+                                lambda: eval_rows(sub.op[:E], sub.arg[:E]))
+            fitness = jnp.concatenate([head, tail])
+        else:
+            fitness = eval_rows(sub.op, sub.arg)
+    with jax.named_scope("gp.select_best"):
+        i = jnp.argmin(fitness)
+        improved = fitness[i] < sub.best_fitness
+        best_op = jnp.where(improved, sub.op[i], sub.best_op)
+        best_arg = jnp.where(improved, sub.arg[i], sub.best_arg)
+        best_fit = jnp.minimum(fitness[i], sub.best_fitness)
 
-    if E:
-        # the tenant breeder selects elites on RAW fitness, so the next
-        # cache is argsort(fitness)[:E] of the evaluated population
-        best = jnp.argsort(fitness)[:E]
-        cache_op, cache_arg = sub.op[best], sub.arg[best]
-        cache_fit = fitness[best]
-    else:
-        cache_op, cache_arg, cache_fit = (sub.cache_op, sub.cache_arg,
-                                          sub.cache_fit)
+        if E:
+            # the tenant breeder selects elites on RAW fitness, so the next
+            # cache is argsort(fitness)[:E] of the evaluated population
+            best = jnp.argsort(fitness)[:E]
+            cache_op, cache_arg = sub.op[best], sub.arg[best]
+            cache_fit = fitness[best]
+        else:
+            cache_op, cache_arg, cache_fit = (sub.cache_op, sub.cache_arg,
+                                              sub.cache_fit)
 
-    breed = ev.make_island_breeder(spec, tourn_draw, elitism)
-    key, new_op, new_arg = breed(sub.key, sub.op, sub.arg, fitness,
-                                 p.probs, p.tourn, p.point_rate)
+    with jax.named_scope("gp.breed"):
+        breed = ev.make_island_breeder(spec, tourn_draw, elitism)
+        key, new_op, new_arg = breed(sub.key, sub.op, sub.arg, fitness,
+                                     p.probs, p.tourn, p.point_rate)
     nxt = TenantState(key, new_op, new_arg, fitness, best_op, best_arg,
                       best_fit, sub.gens_done + 1, cache_op, cache_arg,
                       cache_fit)
@@ -912,7 +930,8 @@ def build_tenant_block(spec: TreeSpec, kernels: tuple, tourn_draw: int,
 
     def block(state: TenantState, X, y, weight, params: TenantParams):
         def body(s, _):
-            row = _tenant_counter_row(s, params)
+            with jax.named_scope("gp.telemetry"):
+                row = _tenant_counter_row(s, params)
             nxt = tenant_step(spec, kernels, tourn_draw, elitism, s, X, y,
                               weight, params, dedup=dedup,
                               dedup_cap=dedup_cap)
@@ -1030,50 +1049,58 @@ def _sharded_step_builder(cfg: GPConfig, mesh, *, data_axis="data",
         # _reduce_moments_on_mesh) and reduce_moments finalizes — for
         # decomposable kernels M == 1 and this degenerates to the
         # classic psum-of-partials
-        partial_m = _eval_moments(cfg, state.op, state.arg, X, y, weight,
-                                  const_table)
-        fitness_local = _reduce_moments_on_mesh(kern, cfg.fitness, partial_m,
-                                                y, weight, data_axis, n_data)
-        # --- selection pool = this pod's population: tiny all_gather
-        fitness_g = jax.lax.all_gather(fitness_local, model_axis, tiled=True)
-        op_g = jax.lax.all_gather(state.op, model_axis, tiled=True)
-        arg_g = jax.lax.all_gather(state.arg, model_axis, tiled=True)
+        with jax.named_scope("gp.eval"):
+            partial_m = _eval_moments(cfg, state.op, state.arg, X, y, weight,
+                                      const_table)
+            fitness_local = _reduce_moments_on_mesh(
+                kern, cfg.fitness, partial_m, y, weight, data_axis, n_data)
+        with jax.named_scope("gp.select_best"):
+            # --- selection pool = this pod's population: tiny all_gather
+            fitness_g = jax.lax.all_gather(fitness_local, model_axis,
+                                           tiled=True)
+            op_g = jax.lax.all_gather(state.op, model_axis, tiled=True)
+            arg_g = jax.lax.all_gather(state.arg, model_axis, tiled=True)
 
-        # --- pod-local best, then global best across pods (replicated)
-        i = jnp.argmin(fitness_g)
-        cand_fit, cand_op, cand_arg = fitness_g[i], op_g[i], arg_g[i]
-        if pod_axis:
-            pods_fit = jax.lax.all_gather(cand_fit, pod_axis)  # [n_pods]
-            pods_op = jax.lax.all_gather(cand_op, pod_axis)  # [n_pods, N]
-            pods_arg = jax.lax.all_gather(cand_arg, pod_axis)
-            j = jnp.argmin(pods_fit)
-            cand_fit, cand_op, cand_arg = pods_fit[j], pods_op[j], pods_arg[j]
-        improved = cand_fit < state.best_fitness
-        best_op = jnp.where(improved, cand_op, state.best_op)
-        best_arg = jnp.where(improved, cand_arg, state.best_arg)
-        best_fit = jnp.minimum(cand_fit, state.best_fitness)
+            # --- pod-local best, then global best across pods (replicated)
+            i = jnp.argmin(fitness_g)
+            cand_fit, cand_op, cand_arg = fitness_g[i], op_g[i], arg_g[i]
+            if pod_axis:
+                pods_fit = jax.lax.all_gather(cand_fit, pod_axis)  # [n_pods]
+                pods_op = jax.lax.all_gather(cand_op, pod_axis)  # [n_pods, N]
+                pods_arg = jax.lax.all_gather(cand_arg, pod_axis)
+                j = jnp.argmin(pods_fit)
+                cand_fit, cand_op, cand_arg = (pods_fit[j], pods_op[j],
+                                               pods_arg[j])
+            improved = cand_fit < state.best_fitness
+            best_op = jnp.where(improved, cand_op, state.best_op)
+            best_arg = jnp.where(improved, cand_arg, state.best_arg)
+            best_fit = jnp.minimum(cand_fit, state.best_fitness)
 
-        # --- offspring for this shard's slice only (decorrelated RNG)
-        rank = jax.lax.axis_index(model_axis)
-        key = state.key
+        with jax.named_scope("gp.breed"):
+            # --- offspring for this shard's slice only (decorrelated RNG)
+            rank = jax.lax.axis_index(model_axis)
+            key = state.key
+            if pod_axis:
+                key = jax.random.fold_in(key, jax.lax.axis_index(pod_axis))
+            key = jax.random.fold_in(key, state.generation)
+            k_rank = jax.random.fold_in(key, rank)
+            n_local = cfg.pop_size // n_shards
+            new_op, new_arg = ev.next_generation(
+                k_rank, op_g, arg_g, fitness_g, cfg.tree_spec, cfg.mix,
+                cfg.tourn_size, elitism=0, n_out=n_local)
+            # elitism: rank 0 of each pod re-seeds the pod's own champion
+            if cfg.elitism:
+                keep = rank == 0
+                new_op = new_op.at[0].set(jnp.where(keep, op_g[i], new_op[0]))
+                new_arg = new_arg.at[0].set(
+                    jnp.where(keep, arg_g[i], new_arg[0]))
         if pod_axis:
-            key = jax.random.fold_in(key, jax.lax.axis_index(pod_axis))
-        key = jax.random.fold_in(key, state.generation)
-        k_rank = jax.random.fold_in(key, rank)
-        n_local = cfg.pop_size // n_shards
-        new_op, new_arg = ev.next_generation(
-            k_rank, op_g, arg_g, fitness_g, cfg.tree_spec, cfg.mix,
-            cfg.tourn_size, elitism=0, n_out=n_local)
-        # elitism: rank 0 of each pod re-seeds the pod's own champion
-        if cfg.elitism:
-            keep = rank == 0
-            new_op = new_op.at[0].set(jnp.where(keep, op_g[i], new_op[0]))
-            new_arg = new_arg.at[0].set(jnp.where(keep, arg_g[i], new_arg[0]))
-        if pod_axis:
-            order = jnp.argsort(fitness_g)[:cfg.migrate_k]
-            new_op, new_arg = migrate(
-                cfg, new_op, new_arg, op_g[order], arg_g[order],
-                state.generation, pod_axis, is_receiver=rank == n_model - 1)
+            with jax.named_scope("gp.migrate"):
+                order = jnp.argsort(fitness_g)[:cfg.migrate_k]
+                new_op, new_arg = migrate(
+                    cfg, new_op, new_arg, op_g[order], arg_g[order],
+                    state.generation, pod_axis,
+                    is_receiver=rank == n_model - 1)
         return GPState(state.key, new_op, new_arg, fitness_local, best_op, best_arg,
                        best_fit, state.generation + 1,
                        state.cache_op, state.cache_arg, state.cache_fit)
@@ -1138,62 +1165,72 @@ def _sharded_island_step_builder(cfg: GPConfig, mesh, *, data_axis="data",
     def step(state: GPState, X, y, weight) -> GPState:
         const_table = cfg.tree_spec.const_table()
         Il, Pl, N = state.op.shape  # per-shard: I_local, pop/model, nodes
-        partial_m = _eval_moments(cfg, state.op.reshape(Il * Pl, N),
-                                  state.arg.reshape(Il * Pl, N), X, y, weight,
-                                  const_table)
-        fitness_local = _reduce_moments_on_mesh(
-            kern, cfg.fitness, partial_m, y, weight, data_axis,
-            n_data).reshape(Il, Pl)
-        # --- selection pool = each island's own population: tiny gathers
-        fitness_g = jax.lax.all_gather(fitness_local, model_axis, axis=1,
-                                       tiled=True)  # [Il, P]
-        op_g = jax.lax.all_gather(state.op, model_axis, axis=1, tiled=True)
-        arg_g = jax.lax.all_gather(state.arg, model_axis, axis=1, tiled=True)
+        with jax.named_scope("gp.eval"):
+            partial_m = _eval_moments(cfg, state.op.reshape(Il * Pl, N),
+                                      state.arg.reshape(Il * Pl, N), X, y,
+                                      weight, const_table)
+            fitness_local = _reduce_moments_on_mesh(
+                kern, cfg.fitness, partial_m, y, weight, data_axis,
+                n_data).reshape(Il, Pl)
+        with jax.named_scope("gp.select_best"):
+            # --- selection pool = each island's own population: tiny gathers
+            fitness_g = jax.lax.all_gather(fitness_local, model_axis, axis=1,
+                                           tiled=True)  # [Il, P]
+            op_g = jax.lax.all_gather(state.op, model_axis, axis=1,
+                                      tiled=True)
+            arg_g = jax.lax.all_gather(state.arg, model_axis, axis=1,
+                                       tiled=True)
 
-        # --- per-island champion (each pod owns its islands' streams)
-        i = jnp.argmin(fitness_g, axis=1)  # [Il]
-        rows = jnp.arange(Il)
-        cand_fit, cand_op, cand_arg = (fitness_g[rows, i], op_g[rows, i],
-                                       arg_g[rows, i])
-        improved = cand_fit < state.best_fitness
-        best_op = jnp.where(improved[:, None], cand_op, state.best_op)
-        best_arg = jnp.where(improved[:, None], cand_arg, state.best_arg)
-        best_fit = jnp.minimum(cand_fit, state.best_fitness)
+            # --- per-island champion (each pod owns its islands' streams)
+            i = jnp.argmin(fitness_g, axis=1)  # [Il]
+            rows = jnp.arange(Il)
+            cand_fit, cand_op, cand_arg = (fitness_g[rows, i], op_g[rows, i],
+                                           arg_g[rows, i])
+            improved = cand_fit < state.best_fitness
+            best_op = jnp.where(improved[:, None], cand_op, state.best_op)
+            best_arg = jnp.where(improved[:, None], cand_arg, state.best_arg)
+            best_fit = jnp.minimum(cand_fit, state.best_fitness)
 
-        sel_fitness = fitness_g
-        if cfg.parsimony:
-            from repro.core.trees import tree_sizes
+            sel_fitness = fitness_g
+            if cfg.parsimony:
+                from repro.core.trees import tree_sizes
 
-            sizes = tree_sizes(op_g.reshape(Il * cfg.pop_size, N))
-            sel_fitness = fitness_g + cfg.parsimony * sizes.reshape(
-                Il, cfg.pop_size).astype(jnp.float32)
+                sizes = tree_sizes(op_g.reshape(Il * cfg.pop_size, N))
+                sel_fitness = fitness_g + cfg.parsimony * sizes.reshape(
+                    Il, cfg.pop_size).astype(jnp.float32)
 
-        # --- offspring for this shard's slice (decorrelated per island
-        # via the per-island key, per rank via fold_in); per-island
-        # search parameters are the pod's slice of the global tables
-        rank = jax.lax.axis_index(model_axis)
-        start = (jax.lax.axis_index(pod) if pod else 0) * Il
-        probs_l = jax.lax.dynamic_slice_in_dim(jnp.asarray(probs_t), start, Il, 0)
-        tourn_l = jax.lax.dynamic_slice_in_dim(jnp.asarray(tourn_t), start, Il, 0)
-        pp_l = jax.lax.dynamic_slice_in_dim(jnp.asarray(pp_t), start, Il, 0)
+        with jax.named_scope("gp.breed"):
+            # --- offspring for this shard's slice (decorrelated per island
+            # via the per-island key, per rank via fold_in); per-island
+            # search parameters are the pod's slice of the global tables
+            rank = jax.lax.axis_index(model_axis)
+            start = (jax.lax.axis_index(pod) if pod else 0) * Il
+            probs_l = jax.lax.dynamic_slice_in_dim(jnp.asarray(probs_t),
+                                                   start, Il, 0)
+            tourn_l = jax.lax.dynamic_slice_in_dim(jnp.asarray(tourn_t),
+                                                   start, Il, 0)
+            pp_l = jax.lax.dynamic_slice_in_dim(jnp.asarray(pp_t), start,
+                                                Il, 0)
 
-        breed = ev.make_island_breeder(cfg.tree_spec, tourn_max, elitism=0,
-                                       n_out=n_local, fold=rank)
-        keys, new_op, new_arg = jax.vmap(breed)(
-            state.key, op_g, arg_g, sel_fitness, probs_l, tourn_l, pp_l)
-        # elitism: rank 0's slice re-seeds each island's own champion
-        if cfg.elitism:
-            keep = rank == 0
-            new_op = new_op.at[:, 0].set(
-                jnp.where(keep, cand_op, new_op[:, 0]))
-            new_arg = new_arg.at[:, 0].set(
-                jnp.where(keep, cand_arg, new_arg[:, 0]))
+            breed = ev.make_island_breeder(cfg.tree_spec, tourn_max,
+                                           elitism=0, n_out=n_local,
+                                           fold=rank)
+            keys, new_op, new_arg = jax.vmap(breed)(
+                state.key, op_g, arg_g, sel_fitness, probs_l, tourn_l, pp_l)
+            # elitism: rank 0's slice re-seeds each island's own champion
+            if cfg.elitism:
+                keep = rank == 0
+                new_op = new_op.at[:, 0].set(
+                    jnp.where(keep, cand_op, new_op[:, 0]))
+                new_arg = new_arg.at[:, 0].set(
+                    jnp.where(keep, cand_arg, new_arg[:, 0]))
         if icfg.migrate_k and I > 1:
-            e_op, e_arg = isl.island_elites(op_g, arg_g, fitness_g,
-                                            icfg.migrate_k)
-            new_op, new_arg = isl.migrate_sharded(
-                icfg, new_op, new_arg, e_op, e_arg, state.generation,
-                cand_fit, pod, is_receiver=rank == n_model - 1)
+            with jax.named_scope("gp.migrate"):
+                e_op, e_arg = isl.island_elites(op_g, arg_g, fitness_g,
+                                                icfg.migrate_k)
+                new_op, new_arg = isl.migrate_sharded(
+                    icfg, new_op, new_arg, e_op, e_arg, state.generation,
+                    cand_fit, pod, is_receiver=rank == n_model - 1)
         return GPState(keys, new_op, new_arg, fitness_local, best_op, best_arg,
                        best_fit, state.generation + 1,
                        state.cache_op, state.cache_arg, state.cache_fit)
@@ -1267,7 +1304,8 @@ def sharded_evolve_block(cfg: GPConfig, mesh, *, n_steps: int, data_axis="data",
     def block(state: GPState, X, y, weight, limit):
         def body(s, i):
             d = done(s, i, limit)
-            row = _counter_row(cfg, s, d, mesh=True, n_pods=n_pods)
+            with jax.named_scope("gp.telemetry"):
+                row = _counter_row(cfg, s, d, mesh=True, n_pods=n_pods)
             nxt = _freeze(d, s, step(s, X, y, weight))
             return nxt, (nxt.best_fitness, row)
 

@@ -208,6 +208,7 @@ def _sorted_signatures(sigf):
 
 
 @partial(jax.jit, static_argnames=("spec", "cap"))
+@jax.named_scope("gp.dedup_plan")
 def build_dedup_plan(op, arg, spec: TreeSpec, cap: int) -> DedupPlan:
     """Canonicalize + sort + unique the population's subtree spans into a
     fixed-shape evaluation schedule. A lexicographic sort of the

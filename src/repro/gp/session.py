@@ -327,7 +327,7 @@ class GPSession:
         footprint is ONE chunk regardless of total rows. On a mesh each
         chunk is sharded on the data axis (chunk_rows rounds up to a
         multiple of it)."""
-        with self.tracer.span("ingest"):
+        with self.tracer.span("fit.ingest"):
             out = self._ingest(X, y, layout=layout,
                                sample_weight=sample_weight, stream=stream,
                                chunk_rows=chunk_rows)
@@ -467,7 +467,7 @@ class GPSession:
         if self._X is None and self._stream is None:
             raise ValueError("no dataset — call ingest()/fit() first")
         key = key if key is not None else jax.random.PRNGKey(0)
-        with self.tracer.span("init"):
+        with self.tracer.span("fit.init_state"):
             self.state = engine.init_state(self._cfg, key, seeds=seeds,
                                            feature_names=self.feature_names)
             self.history = []
@@ -475,11 +475,12 @@ class GPSession:
             self._gen_host = 0
             self._gen_dirty = False
             if self._manager is not None:
-                restored, step = self._manager.restore_latest(
-                    like=jax.device_get(self.state))
-                if restored is not None:
-                    self.state = jax.tree.map(jnp.asarray, restored)
-                    self._gen_host = int(step)
+                with self.tracer.span("fit.init_restore"):
+                    restored, step = self._manager.restore_latest(
+                        like=jax.device_get(self.state))
+                    if restored is not None:
+                        self.state = jax.tree.map(jnp.asarray, restored)
+                        self._gen_host = int(step)
         return self
 
     # --- slot-level state swap (the service scheduler's surface) -------------
@@ -687,7 +688,7 @@ class GPSession:
                     # per-chunk host-side cost (place + dispatch; the fold
                     # itself is async) — no sync is added for timing
                     t0 = time.perf_counter()
-                    with self.tracer.span("chunk"):
+                    with self.tracer.span("fit.chunk"):
                         acc = self._stream_fold(acc, op, arg,
                                                 jax.device_put(X, sh_X),
                                                 jax.device_put(y, sh_y),
@@ -696,7 +697,7 @@ class GPSession:
             fitness = kern.reduce_moments(acc, cfg.fitness)
         else:
             t0 = time.perf_counter()
-            with self.tracer.span("stream_fold"):
+            with self.tracer.span("fit.stream_fold"):
                 fitness = engine.chunked_fitness(cfg, op, arg, self._stream,
                                                  impl=self._backend.name)
             self.metrics.observe("stream_fold_s", time.perf_counter() - t0)
@@ -884,7 +885,7 @@ class GPSession:
             self.history.append(best)
             self._count_host_sync()
             if self._manager is not None:
-                with self.tracer.span("checkpoint"):
+                with self.tracer.span("fit.checkpoint"):
                     self._manager.maybe_save(self.state, self._gen_host)
             stopped = cfg.stop_fitness is not None and best <= cfg.stop_fitness
             if self._callback is not None and (
@@ -924,35 +925,43 @@ class GPSession:
                 block_idx = self.stats["blocks"]
                 # the monitor times dispatch THROUGH the block-boundary
                 # sync — the span a straggling host/device would stretch
-                with self._block_monitor, self.tracer.span(
-                        "block", args={"k": K, "quantum": quantum}), \
-                        self.tracer.maybe_profile(block_idx):
-                    _, history, counters = self._dispatch_block(quantum, K)
+                # (the armed profiler window opens first, so that it
+                # holds this block's own span)
+                with self._block_monitor, \
+                        self.tracer.maybe_profile(block_idx), \
+                        self.tracer.span("fit.block",
+                                         args={"k": K, "quantum": quantum}):
+                    with self.tracer.span("fit.dispatch"):
+                        _, history, counters = self._dispatch_block(quantum, K)
                     # ONE sync per block: final generation counter, the
                     # best-fitness stream and the telemetry counter
                     # stream come back together
-                    gen_now, hist, crows = jax.device_get(
-                        (self.state.generation, history, counters))
-                gen_now = int(gen_now)
-                self._count_host_sync()
-                self._last_counters = None  # absorbed here, same sync
-                self._absorb_counters(crows)
-                ran = gen_now - prev_gen
-                self._gen_host = gen_now
-                self.metrics.gauge("generation", gen_now)
-                if ran and self._monitor.last:
-                    self.metrics.gauge("gens_per_s", ran / self._monitor.last)
-                rows = hist[:ran]
-                if hist.ndim == 2:  # island run: [K, I] per-island streams
-                    self.island_history.extend(np.asarray(rows))
-                    rows = rows.min(axis=1)
-                self.history.extend(float(b) for b in rows)
+                    with self.tracer.span("fit.sync"):
+                        gen_now, hist, crows = jax.device_get(
+                            (self.state.generation, history, counters))
+                with self.tracer.span("fit.absorb"):
+                    gen_now = int(gen_now)
+                    self._count_host_sync()
+                    self._last_counters = None  # absorbed here, same sync
+                    self._absorb_counters(crows)
+                    ran = gen_now - prev_gen
+                    self._gen_host = gen_now
+                    self.metrics.gauge("generation", gen_now)
+                    if ran and self._monitor.last:
+                        self.metrics.gauge("gens_per_s",
+                                           ran / self._monitor.last)
+                    rows = hist[:ran]
+                    if hist.ndim == 2:  # island run: [K, I] per island
+                        self.island_history.extend(np.asarray(rows))
+                        rows = rows.min(axis=1)
+                    self.history.extend(float(b) for b in rows)
+                    stopped = ran < K or (
+                        cfg.stop_fitness is not None and ran
+                        and rows[ran - 1] <= cfg.stop_fitness)
+                    last = stopped or gen_now >= target
                 if self._manager is not None:
-                    with self.tracer.span("checkpoint"):
+                    with self.tracer.span("fit.checkpoint"):
                         self._manager.maybe_save(self.state, gen_now)
-                stopped = ran < K or (cfg.stop_fitness is not None and ran
-                                      and rows[ran - 1] <= cfg.stop_fitness)
-                last = stopped or gen_now >= target
                 if self._callback is not None and ran and (
                         gen_now % self._callback_every == 0 or last):
                     self._callback(gen_now - 1, self.state)
@@ -960,7 +969,7 @@ class GPSession:
                     break
         if self._manager is not None:
             # final save, unless the last block boundary already saved here
-            with self.tracer.span("checkpoint"):
+            with self.tracer.span("fit.checkpoint"):
                 self._manager.wait()
                 if (not self._manager.saved_steps
                         or self._manager.saved_steps[-1] != self._gen_host):

@@ -302,6 +302,7 @@ def eval_fitness_pallas_postfix(op, arg, lens, X, y, weight,
         fn_codes=fn_codes)
     return pl.pallas_call(
         body,
+        name="gp_postfix_eval",
         grid=(P // pop_tile, D // data_tile),
         in_specs=[
             pl.BlockSpec((pop_tile, N), lambda i, j: (i, 0)),
@@ -363,6 +364,7 @@ def eval_fitness_pallas_from_subtrees(root, uniq, y, weight, *,
         precision=precision)
     return pl.pallas_call(
         body,
+        name="gp_subtree_eval",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(P // pop_tile, D // data_tile),
@@ -417,6 +419,7 @@ def eval_fitness_pallas_from_preds(preds, y, weight, *, kernel: str = "r",
         precision=precision)
     return pl.pallas_call(
         body,
+        name="gp_preds_eval",
         grid=(P // pop_tile, D // data_tile),
         in_specs=[
             pl.BlockSpec((pop_tile, data_tile), lambda i, j: (i, j)),
@@ -457,6 +460,7 @@ def eval_fitness_pallas(op, arg, X, y, weight, const_table, *,
         fn_codes=fn_codes)
     return pl.pallas_call(
         body,
+        name="gp_tree_eval",
         grid=(P // pop_tile, D // data_tile),
         in_specs=[
             pl.BlockSpec((pop_tile, N), lambda i, j: (i, 0)),

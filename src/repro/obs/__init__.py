@@ -7,9 +7,11 @@ Three pieces (see docs/observability.md):
   telemetry rides the existing one-sync-per-block dispatch and is
   computed unconditionally, so enabling it never recompiles and never
   changes a trajectory.
-- `trace.Tracer`: Chrome-trace-event JSON spans (Perfetto-viewable)
-  for ingest, block dispatch, chunk folds, checkpoints, and service
-  admission/eviction/job lifetimes; `NULL_TRACER` is the no-op default.
+- `trace`: host spans (`fit.*`, `serve.*`), each a
+  `jax.profiler.TraceAnnotation` on the profiler's clock; a `Tracer`
+  also writes them as Chrome-trace-event JSON (Perfetto-viewable), with
+  the service's job lifetimes as async lanes. `NULL_TRACER`, the
+  default, keeps the annotation alone.
 - `metrics.Metrics`: counters/gauges/EMA summaries with a JSONL sink;
   `metrics.BlockMonitor` routes ALL block timing through one
   `runtime.fault.StepMonitor` wrapper. `python -m repro.obs.report`

@@ -1,20 +1,31 @@
-"""Chrome-trace-event tracing for the GP stack (host side).
+"""Host spans for the GP stack: one span, two sinks.
 
-A `Tracer` collects trace events in memory and writes the Chrome Trace
-Event JSON object format (`{"traceEvents": [...]}`) — open the file at
-`chrome://tracing` or https://ui.perfetto.dev to see ingest, block
-dispatches, chunk folds, checkpoint saves and service admission/
-eviction as nested spans on a per-thread timeline, and per-job
-lifetimes as async tracks. `NULL_TRACER` is the always-on no-op every
-instrumented call site defaults to, so tracing-off costs one attribute
-lookup and no allocation — the device programs never see the tracer at
-all (the counter stream is unconditional; see obs/counters.py), which
-is what keeps traced and untraced trajectories bitwise identical.
+Every span opens a `jax.profiler.TraceAnnotation` under its name, so
+while a profiler session runs (`jax.profiler.trace`, `--profile-dir`,
+a benchmark's traced window) the span sits on the profiler's host
+plane beside the device operations it dispatched — on the profiler's
+clock, where a device idle gap can be put down to the host span open
+at that moment. With no profiler running an annotation records nothing
+and costs about a microsecond. `span(name)` is that annotation alone:
+`NULL_TRACER.span` returns it, and code with no tracer handle
+(`core/engine.py`) calls it directly.
 
-Span discipline: `span()` emits a "B" event and ALWAYS emits the
+A `Tracer` additionally collects trace events in memory and writes the
+Chrome Trace Event JSON object format (`{"traceEvents": [...]}`) — open
+the file at `chrome://tracing` or https://ui.perfetto.dev to see
+ingest, block dispatches, chunk folds, checkpoint saves and service
+admission/dispatch as nested spans on a per-thread timeline, and
+per-job lifetimes as async tracks. The Chrome sink keeps its own
+`perf_counter` clock. The device programs never see the tracer at all
+(the counter stream is unconditional; see obs/counters.py), which is
+what keeps traced and untraced trajectories bitwise identical.
+
+Span discipline: `Tracer.span()` emits a "B" event and ALWAYS emits the
 matching "E" on exit (try/finally), so every written trace nests
 properly — tests/test_obs.py walks the B/E stack per thread and
 rejects orphans. Async job lifetimes use "b"/"e" events keyed by id.
+`args` go to the Chrome sink only; the annotation carries the bare
+name, so both sinks name a span alike.
 
 An optional `jax.profiler` window can be armed around one chosen
 evolution block (`profile_dir=`, `profile_block=`): the session asks
@@ -30,21 +41,27 @@ import threading
 import time
 from contextlib import contextmanager, nullcontext
 
+from jax.profiler import TraceAnnotation
+
+
+def span(name: str) -> TraceAnnotation:
+    """A host span on the profiler's clock: recorded under `name` while a
+    profiler session runs, nothing otherwise. Pass the bare name — the
+    annotation's keyword form would rename the event."""
+    return TraceAnnotation(name)
+
 
 class NullTracer:
-    """No-op tracer: every method returns immediately; `span`/`maybe_
-    profile` return a shared nullcontext. Instrumented code calls the
-    tracer unconditionally and never branches on enablement."""
+    """Tracer with no Chrome sink: `span` is the profiler annotation
+    alone, every other method returns immediately. Instrumented code
+    calls the tracer unconditionally and never branches on enablement."""
 
     enabled = False
 
     def span(self, name, cat="repro", args=None):
-        return nullcontext()
+        return span(name)
 
     def instant(self, name, cat="repro", args=None):
-        pass
-
-    def counter(self, name, values, cat="repro"):
         pass
 
     def begin_async(self, name, aid, cat="repro", args=None):
@@ -104,15 +121,17 @@ class Tracer:
             ev["args"] = dict(args)
         return ev
 
-    # --- spans / instants / counters ------------------------------------------
+    # --- spans / instants -----------------------------------------------------
 
     @contextmanager
     def span(self, name, cat="repro", args=None):
         """Duration span: B on entry, E on exit — the E is emitted even
-        when the body raises, so traces always nest."""
+        when the body raises, so traces always nest. The profiler
+        annotation of the same name opens inside the pair."""
         self._emit(self._base("B", name, cat, args))
         try:
-            yield self
+            with span(name):
+                yield self
         finally:
             self._emit(self._base("E", name, cat, None))
 
@@ -120,11 +139,6 @@ class Tracer:
         ev = self._base("i", name, cat, args)
         ev["s"] = "t"  # thread-scoped instant
         self._emit(ev)
-
-    def counter(self, name, values: dict, cat="repro"):
-        """Chrome counter track: `values` is {series: number}."""
-        self._emit(self._base("C", name, cat,
-                              {k: float(v) for k, v in values.items()}))
 
     def begin_async(self, name, aid, cat="repro", args=None):
         """Open an async lifetime lane. Idempotent per (name, id): a

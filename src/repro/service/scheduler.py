@@ -244,8 +244,9 @@ class GPService:
         free = self.batch.free_slots
         if not free or not self._pending:
             return
-        with self.tracer.span("admit", args={"free": len(free),
-                                             "pending": len(self._pending)}):
+        with self.tracer.span("serve.admit",
+                              args={"free": len(free),
+                                    "pending": len(self._pending)}):
             chosen = pack_order(self._pending, len(free), self.strategy)
             for slot, handle in zip(free, chosen):
                 self._pending.remove(handle)
@@ -264,15 +265,16 @@ class GPService:
                 self.stats["admissions"] += 1
                 self.metrics.inc("admissions")
                 # async track: one lifetime lane per job, admission → publish
-                self.tracer.begin_async("job", handle.job_id, cat="service",
-                                        args={"slot": slot})
+                self.tracer.begin_async("serve.job", handle.job_id,
+                                        cat="service", args={"slot": slot})
         self.metrics.gauge("occupied_slots", len(self.batch.occupied))
 
     def _dispatch_and_publish(self):
         X, y, w, params = self.batch.operands()
         block = self._compiled_block(X, y, w, params)
         with self._block_monitor, self.tracer.span(
-                "dispatch", args={"occupied": len(self.batch.occupied)}):
+                "serve.dispatch",
+                args={"occupied": len(self.batch.occupied)}):
             self._state, hist, counters = block(self._state, X, y, w, params)
             # ONE host sync per block: counters, champions and the
             # per-generation streams come back together
@@ -336,10 +338,10 @@ class GPService:
         self.heartbeats.remove(self._worker_id(handle))
         self.stats["evictions"] += 1
         self.metrics.inc("evictions")
-        self.tracer.end_async("job", handle.job_id, cat="service",
+        self.tracer.end_async("serve.job", handle.job_id, cat="service",
                               args={"status": status,
                                     "gens": handle.gens_done})
-        self.tracer.instant("publish", cat="service",
+        self.tracer.instant("serve.publish", cat="service",
                             args={"job": handle.job_id, "status": status})
 
     def _worker_id(self, handle: JobHandle) -> str:
@@ -396,7 +398,8 @@ class GPService:
             handle.status = RUNNING
             # a rollback puts the job back in flight: reopen its lifetime
             # lane (idempotent — a still-open lane is untouched)
-            self.tracer.begin_async("job", handle.job_id, cat="service",
+            self.tracer.begin_async("serve.job", handle.job_id,
+                                    cat="service",
                                     args={"slot": i, "rollback": True})
             handle.gens_done = int(gens[i])
             handle.best_fitness = float(best[i])

@@ -5,9 +5,14 @@ are computed unconditionally inside the compiled evolution blocks, so
 turning tracing/metrics on must not recompile anything, add host syncs,
 or perturb a single bit of the trajectory. These tests pin that, plus
 the trace-file schema (valid Chrome trace JSON, properly nested spans,
-paired async job lanes), the elite-cache hit-rate surface on both the
-session and the service, and the `repro.obs.report` summarizer.
+paired async job lanes), the program's spans on the profiler's host
+plane under the names the Chrome sink writes, the elite-cache hit-rate
+surface on both the session and the service, and the `repro.obs.report`
+summarizer.
 """
+import collections
+import contextlib
+import glob
 import json
 import os
 
@@ -19,8 +24,45 @@ from repro.core import engine
 from repro.data.datasets import kepler
 from repro.gp import GPSession
 from repro.obs import Metrics, NULL_TRACER, Tracer, counters, validate_trace
+from repro.obs import trace as obs_trace
 from repro.obs.metrics import BlockMonitor
 from repro.service import GPService, JobSpec
+
+PROGRAM_PREFIXES = ("fit.", "serve.")
+
+
+@contextlib.contextmanager
+def _profiler(logdir):
+    """A profiler session writing under `logdir` (no Python tracer, as
+    in the benchmark's traced window)."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(logdir), profiler_options=opts)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+
+
+def _host_spans(logdir):
+    """(name, start_ns, end_ns) of every program span on the host planes
+    of the one profile under `logdir`."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True)
+    data = ProfileData.from_file(path)
+    return [(e.name, e.start_ns, e.end_ns) for plane in data.planes
+            if plane.name.startswith("/host:") for line in plane.lines
+            for e in line.events if e.name.startswith(PROGRAM_PREFIXES)]
+
+
+def _chrome_spans(tracer):
+    return collections.Counter(e["name"] for e in tracer.events
+                               if e["ph"] == "B")
+
+
+def _inside(child, parents):
+    return any(s <= child[1] and child[2] <= e for _, s, e in parents)
 
 
 def _jobs(n=3, rows=48, seed=0):
@@ -37,15 +79,19 @@ def _jobs(n=3, rows=48, seed=0):
 # --- tentpole: no observer effects -------------------------------------------
 
 
+@pytest.mark.parametrize("profiler", [False, True])
 @pytest.mark.parametrize("backend", ["jnp", "pallas"])
 @pytest.mark.parametrize("islands", [1, 3])
 @pytest.mark.parametrize("genome", ["tree", "postfix"])
-def test_telemetry_on_off_bitwise_parity(backend, islands, genome, tmp_path):
+def test_telemetry_on_off_bitwise_parity(backend, islands, genome, profiler,
+                                         tmp_path):
     """Tracing + metrics ON yields the bitwise-identical best-fitness
     trajectory, the same generation count and the same host-sync budget
     as OFF — across backend × island layout × genome. The counter stream
     is unconditional in the compiled program, so enablement is purely a
-    host-side concern."""
+    host-side concern. The OFF run's spans are the bare profiler
+    annotations, recording nothing; with `profiler` the ON run also
+    writes every span to a running profiler session."""
     X_rows, y, _ = kepler()
     kw = dict(pop_size=16, generations=10, kernel="r", backend=backend,
               genome=genome, islands=islands, migrate_every=3, migrate_k=2,
@@ -56,8 +102,12 @@ def test_telemetry_on_off_bitwise_parity(backend, islands, genome, tmp_path):
     tracer = Tracer(str(tmp_path / "trace.json"))
     mreg = Metrics(str(tmp_path / "metrics.jsonl"))
     on = GPSession(tracer=tracer, metrics=mreg, **kw)
-    on.fit(X_rows, y, key=jax.random.PRNGKey(0))
+    with (_profiler(tmp_path / "prof") if profiler
+          else contextlib.nullcontext()):
+        on.fit(X_rows, y, key=jax.random.PRNGKey(0))
     mreg.close()
+    if profiler:
+        assert "fit.block" in {n for n, _, _ in _host_spans(tmp_path / "prof")}
 
     np.testing.assert_array_equal(np.asarray(off.history),
                                   np.asarray(on.history))
@@ -165,7 +215,8 @@ def test_service_cache_hit_rate_and_no_recompile(tmp_path):
     phases = {e["ph"] for e in payload["traceEvents"]}
     assert {"b", "e", "B", "E"} <= phases
     names = {e["name"] for e in payload["traceEvents"]}
-    assert {"admit", "dispatch", "job"} <= names
+    assert {"serve.admit", "serve.dispatch", "serve.job",
+            "serve.publish"} <= names
 
 
 def test_service_elitism_zero_disables_cache_counters():
@@ -183,7 +234,8 @@ def test_service_elitism_zero_disables_cache_counters():
 
 def test_trace_schema_and_nesting(tmp_path):
     """A real session run writes valid Chrome trace JSON: envelope,
-    nested B/E spans (ingest, block, checkpoint), no orphan E events."""
+    nested B/E spans (fit.ingest, fit.block, fit.checkpoint), no orphan
+    E events."""
     X_rows, y, _ = kepler()
     path = str(tmp_path / "t.json")
     tracer = Tracer(path)
@@ -197,11 +249,114 @@ def test_trace_schema_and_nesting(tmp_path):
     assert validate_trace(payload) == []
     assert isinstance(payload["traceEvents"], list)
     names = {e["name"] for e in payload["traceEvents"]}
-    assert {"ingest", "init", "block", "checkpoint"} <= names
+    assert {"fit.ingest", "fit.init_state", "fit.block", "fit.dispatch",
+            "fit.sync", "fit.absorb", "fit.checkpoint"} <= names
     # every B has ts/pid/tid — the fields Perfetto needs to lay out lanes
     for ev in payload["traceEvents"]:
         if ev["ph"] in ("B", "E"):
             assert {"ts", "pid", "tid"} <= set(ev)
+
+
+@pytest.mark.parametrize("path", ["blocks", "stream"])
+def test_profiler_sees_session_spans(path, tmp_path):
+    """Every session span reaches the profiler's host plane under the
+    name the Chrome sink writes, nested as the host loop nests them:
+    the population and the restore inside `fit.init_state`, dispatch and
+    sync inside `fit.block`, the absorb and the checkpoint after it.
+    `fit.init_population` is opened by the engine, which holds no
+    tracer, so it is the one span on the profiler alone. No program span
+    takes a name of the benchmark's own (`fit.init`, `fit.evolve`)."""
+    X_rows, y, _ = kepler()
+    tracer = Tracer()
+    if path == "blocks":
+        kw = dict(pop_size=16, kernel="r", backend="jnp", block_size=3,
+                  checkpoint_dir=str(tmp_path / "ck"), checkpoint_every=3)
+        GPSession(generations=6, **kw).fit(X_rows, y)  # leaves a checkpoint
+        sess = GPSession(generations=6, tracer=tracer, **kw)
+        want = {"fit.ingest", "fit.init_state", "fit.init_population",
+                "fit.init_restore", "fit.block", "fit.dispatch", "fit.sync",
+                "fit.absorb", "fit.checkpoint"}
+    else:
+        sess = GPSession(pop_size=16, generations=2, kernel="r",
+                         backend="jnp", chunk_rows=4, tracer=tracer)
+        want = {"fit.ingest", "fit.init_state", "fit.init_population",
+                "fit.stream_fold"}
+    with _profiler(tmp_path / "prof"):
+        sess.fit(X_rows, y, key=jax.random.PRNGKey(0))
+    spans = _host_spans(tmp_path / "prof")
+    names = collections.Counter(n for n, _, _ in spans)
+    assert set(names) == want
+    assert names - collections.Counter(["fit.init_population"]) \
+        == _chrome_spans(tracer)
+
+    def of(name):
+        return [s for s in spans if s[0] == name]
+
+    for child, parent in [("fit.init_population", "fit.init_state"),
+                          ("fit.init_restore", "fit.init_state"),
+                          ("fit.dispatch", "fit.block"),
+                          ("fit.sync", "fit.block")]:
+        assert all(_inside(c, of(parent)) for c in of(child)), child
+    for after in ("fit.absorb", "fit.checkpoint"):
+        assert not any(_inside(c, of("fit.block")) for c in of(after))
+    assert not {"fit.init", "fit.evolve"} & set(names)
+
+
+def test_armed_profile_window_holds_its_block_spans(tmp_path):
+    """`profile_dir=`/`profile_block=` profile one block, and that
+    window holds the block's own spans, dispatch and sync included."""
+    X_rows, y, _ = kepler()
+    tracer = Tracer(profile_dir=str(tmp_path / "prof"), profile_block=1)
+    GPSession(pop_size=16, generations=9, kernel="r", backend="jnp",
+              block_size=3, tracer=tracer).fit(X_rows, y)
+    names = collections.Counter(n for n, _, _ in _host_spans(tmp_path / "prof"))
+    assert names == {"fit.block": 1, "fit.dispatch": 1, "fit.sync": 1}
+
+
+def test_profiler_sees_service_spans(tmp_path):
+    """The service's admission and dispatch spans reach the profiler as
+    they reach the Chrome sink; the job lanes and the publish instant
+    are Chrome events only."""
+    tracer = Tracer()
+    svc = GPService(slots=2, pop_size=32, n_features=3, data_cap=64,
+                    block_size=4, tracer=tracer)
+    for j in _jobs(3):
+        svc.submit(j)
+    with _profiler(tmp_path / "prof"):
+        svc.run()
+    names = collections.Counter(n for n, _, _ in _host_spans(tmp_path / "prof"))
+    assert set(names) == {"serve.admit", "serve.dispatch"}
+    assert names == _chrome_spans(tracer)
+
+
+def test_profiler_off_records_nothing(tmp_path):
+    """Spans opened while no profiler runs leave nothing behind: a
+    profiler session opened afterwards holds none of them."""
+    X_rows, y, _ = kepler()
+    GPSession(pop_size=16, generations=4, kernel="r", backend="jnp",
+              tracer=Tracer()).fit(X_rows, y)
+    GPSession(pop_size=16, generations=4, kernel="r",
+              backend="jnp").fit(X_rows, y)
+    with _profiler(tmp_path / "prof"):
+        pass
+    assert _host_spans(tmp_path / "prof") == []
+
+
+def test_span_annotation_carries_the_bare_name(tmp_path):
+    """`args` reach the Chrome sink only: on the profiler every door to
+    a span — the Tracer, the null tracer and the module helper — writes
+    the name alone, so both sinks name a span alike."""
+    tracer = Tracer()
+    with _profiler(tmp_path / "prof"):
+        with tracer.span("fit.a", args={"k": 3}):
+            pass
+        with NULL_TRACER.span("fit.b", args={"k": 3}):
+            pass
+        with obs_trace.span("fit.c"):
+            pass
+    names = [n for n, _, _ in _host_spans(tmp_path / "prof")]
+    assert sorted(names) == ["fit.a", "fit.b", "fit.c"]
+    assert [e["args"] for e in tracer.events if e["ph"] == "B"] == [{"k": 3}]
 
 
 def test_validate_trace_catches_malformed():
@@ -314,7 +469,7 @@ def test_report_summarizes_run_artifacts(tmp_path, capsys):
     out = capsys.readouterr().out
     assert "trace: valid" in out
     assert "cache hit rate" in out
-    assert "block" in out
+    assert "fit.block" in out
 
 
 def test_absorb_block_telemetry_raw_surface():

@@ -16,6 +16,7 @@ compilation cache is off around the compiles: their entries could not
 be read back without a chip.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -127,3 +128,32 @@ def test_dedup_spill_kernel_compiles(one_chip):
                     _sds(one_chip, (D,), jnp.float32),
                     _sds(one_chip, (D,), jnp.float32)).compile().as_text()
     assert _kernels(text) == 1
+
+
+def test_tree_fit_block_carries_scopes_and_kernel_name(one_chip, monkeypatch):
+    """The KAT-7 tree-fit evolution block (Table 2's population 100 over
+    90,000 rows, kernel c), lowered for the described chip: its ops carry
+    the engine's layer scopes and the tree kernel its own name, which is
+    what a profile of the block shows."""
+    from repro.core import engine
+    from repro.core.fitness import FitnessSpec as Fit
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    spec = TreeSpec(max_depth=DEPTH, n_features=KAT7_F, n_consts=C,
+                    fn_set=prim.CLASSIFY_SET)
+    cfg = engine.GPConfig(tree_spec=spec, pop_size=100, tourn_size=10,
+                          generations=30, fitness=Fit("c", n_classes=2),
+                          eval_impl="pallas")
+    state = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda k: engine.init_state(cfg, k),
+                       jax.random.PRNGKey(0)))
+    rows = 90_000
+    text = engine.evolve_block.lower(
+        cfg, state, _sds(one_chip, (KAT7_F, rows), jnp.float32),
+        _sds(one_chip, (rows,), jnp.float32), None,
+        _sds(one_chip, (), jnp.int32), n_steps=30).as_text(debug_info=True)
+    for scope in ("gp.eval", "gp.select_best", "gp.breed", "gp.telemetry"):
+        # a name-stack component of some op's location
+        assert re.search(rf'["/]{re.escape(scope)}[/"]', text), scope
+    assert 'kernel_name = "gp_tree_eval"' in text
