@@ -36,6 +36,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import evolve as ev
 from repro.core import fitness as fit
+from repro.core import primitives as prim
 from repro.core.islands import IslandConfig
 from repro.core.trees import TreeSpec, generate_population
 from repro.obs.trace import span as host_span
@@ -518,11 +519,13 @@ def _counter_row(cfg: GPConfig, state: GPState, done=None, *, mesh=False,
 
     `done` is the block's freeze predicate for this step (None = the
     block can never freeze); a frozen step reports
-    [0, 0, 1, 0, 0, 0, 0] — its compute ran and was discarded. With
+    [0, 0, 1, 0, 0, 0, 0, 0] — its compute ran and was discarded. With
     `mesh=True` every quantity is replicated across shards (cache AND
     dedup columns are 0 there: the elite cache is host/single-device
     machinery, and re-running the dedup signature sort per shard purely
-    for telemetry would double the mesh's plan cost) so the counter
+    for telemetry would double the mesh's plan cost; NODE_EVALS is 0
+    there too: where a shard holds part of the population, summing it
+    would add a collective to every generation) so the counter
     stream's out_spec is P(); `n_pods` sizes the classic mesh pod-ring
     migration count.
 
@@ -549,6 +552,11 @@ def _counter_row(cfg: GPConfig, state: GPState, done=None, *, mesh=False,
     # tree evaluations this generation (cache-served rows excluded);
     # the host multiplies by the dataset row count for trees·rows
     evals = jnp.asarray(I * cfg.pop_size, jnp.int32) - hit * (I * E)
+    if mesh:
+        nodes = zero
+    else:
+        active = (state.op != prim.EMPTY).astype(jnp.int32)
+        nodes = active.sum() - hit * active[..., :E, :].sum()
     if island and cfg.island.migrate_k:
         due = ((state.generation % cfg.island.migrate_every)
                == (cfg.island.migrate_every - 1))
@@ -569,10 +577,12 @@ def _counter_row(cfg: GPConfig, state: GPState, done=None, *, mesh=False,
         a = state.arg.reshape(-1, N)
         cap = resolve_dedup_cap(cfg.dedup_cap, o.shape[0], N)
         uniq, saved = dedup_stats(o, a, cfg.tree_spec, cap)
-    row = jnp.stack([hit, queries, zero, migrations, evals, saved, uniq])
+    row = jnp.stack([hit, queries, zero, migrations, evals, saved, uniq,
+                     nodes])
     if done is None:
         return row
-    return jnp.where(done, jnp.asarray([0, 0, 1, 0, 0, 0, 0], jnp.int32), row)
+    return jnp.where(done, jnp.asarray([0, 0, 1, 0, 0, 0, 0, 0], jnp.int32),
+                     row)
 
 
 def _block_done(cfg: GPConfig, state: GPState, i, limit):
@@ -885,7 +895,8 @@ def _tenant_counter_row(state: TenantState, params: TenantParams):
     count per ACTIVE slot (the per-slot gates the slot steps are about
     to take); FROZEN counts inactive slots — finished, early-stopped,
     or empty — whose compute runs and is discarded this generation;
-    TREE_EVALS sums each active slot's non-cache-served rows. Computed
+    TREE_EVALS sums each active slot's non-cache-served rows and
+    NODE_EVALS their active genome slots. Computed
     unconditionally, like every counter row, so the service's
     no-recompile guarantee is untouched. The dedup columns are 0 here,
     like the cache columns on a mesh: slot steps dedup their own row
@@ -905,8 +916,11 @@ def _tenant_counter_row(state: TenantState, params: TenantParams):
         hits = queries = jnp.asarray(0, jnp.int32)
     frozen = (1 - a32).sum()
     evals = (a32 * (P_ - h32 * E)).sum()
+    active = (state.op != prim.EMPTY).astype(jnp.int32)
+    nodes = (a32 * (active.sum((1, 2))
+                    - h32 * active[:, :E].sum((1, 2)))).sum()
     zero = jnp.asarray(0, jnp.int32)
-    return jnp.stack([hits, queries, frozen, zero, evals, zero, zero])
+    return jnp.stack([hits, queries, frozen, zero, evals, zero, zero, nodes])
 
 
 def build_tenant_block(spec: TreeSpec, kernels: tuple, tourn_draw: int,

@@ -200,7 +200,7 @@ class GPSession:
         self.stats = {"host_syncs": 0, "blocks": 0, "block_s_ema": None,
                       "stragglers": [], "cache_hits": 0, "cache_queries": 0,
                       "cache_hit_rate": 0.0, "frozen": 0, "migrations": 0,
-                      "tree_evals": 0}
+                      "tree_evals": 0, "node_evals": 0}
         self._monitor = _StepMonitor()  # per-block wall time EMA + stragglers
         # observability (repro.obs): tracer spans + metrics registry are
         # host-side only — the compiled programs are identical with or
@@ -632,7 +632,8 @@ class GPSession:
             self.metrics.inc("tree_row_evals", tot["tree_evals"] * self._n_rows)
         self.metrics.emit("counters", **tot)
 
-    def _record_host_eval(self, hit: int, queries: int, evals: int):
+    def _record_host_eval(self, hit: int, queries: int, evals: int,
+                          nodes: int):
         """Host-path twin of the device counter stream: the scalar/stream
         generation loops compute their elite-cache gate on the host, so
         the same telemetry columns land without any device work."""
@@ -644,6 +645,8 @@ class GPSession:
             self.metrics.inc("cache_hits", hit)
         self.stats["tree_evals"] += evals
         self.metrics.inc("tree_evals", evals)
+        self.stats["node_evals"] += nodes
+        self.metrics.inc("node_evals", nodes)
         self.stats["cache_hit_rate"] = _tc.hit_rate(self.stats)
 
     def absorb_block_telemetry(self) -> dict:
@@ -728,7 +731,8 @@ class GPSession:
         hit = E and (np.array_equal(op_h[:E], np.asarray(state.cache_op))
                      and np.array_equal(arg_h[:E], np.asarray(state.cache_arg)))
         self._record_host_eval(int(bool(hit)), 1 if E else 0,
-                               op_h.shape[0] - (E if hit else 0))
+                               op_h.shape[0] - (E if hit else 0),
+                               int((op_h[E if hit else 0:] != prim.EMPTY).sum()))
         if hit:
             fitness = np.concatenate([np.asarray(state.cache_fit),
                                       eval_rows(op_h[E:], arg_h[E:])])
@@ -779,7 +783,8 @@ class GPSession:
         hit = E and (np.array_equal(op3[:, :E], np.asarray(state.cache_op))
                      and np.array_equal(arg3[:, :E], np.asarray(state.cache_arg)))
         self._record_host_eval(int(bool(hit)), 1 if E else 0,
-                               I * P - (I * E if hit else 0))
+                               I * P - (I * E if hit else 0),
+                               int((op3[:, E if hit else 0:] != prim.EMPTY).sum()))
         if hit:
             tail = eval_rows(op3[:, E:].reshape(-1, N),
                              arg3[:, E:].reshape(-1, N)).reshape(I, P - E)

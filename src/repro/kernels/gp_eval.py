@@ -1,23 +1,36 @@
-"""Pallas TPU kernel: fused GP population evaluation + fitness reduction.
+"""Pallas TPU kernels: fused GP population evaluation + fitness reduction.
 
 This is the compute hot spot the paper optimizes (§2.5: "the evaluation of
 the multivariate expression derived from each GP tree against the entire
 training dataset"). The pure-jnp path (kernels/ref.py → core/eval.py)
 materializes a [pop, nodes, data] intermediate in HBM between the
-level-sweep and the fitness reduction; this kernel keeps the whole
-evaluation frontier in VMEM per (population-tile × data-tile) block and
-writes back only the [pop] fitness partials — turning a memory-bound
-HBM-streaming computation into a VMEM-resident one.
+level-sweep and the fitness reduction; these kernels keep the evaluation
+in VMEM and write back only the [pop, M] fitness moments.
 
-Layout rules every kernel here follows, because they are what Mosaic (the
-TPU kernel compiler) accepts:
+The tree kernel, `gp_tree_eval` (`eval_fitness_pallas_tree`), is the one
+kernel of the heap-tree genome. It evaluates one tree at a time: the
+tree's opcodes are SMEM scalars, each active function slot applies only
+its own operator (a scalar branch on its opcode), and every value is a
+dense `[data_tile / 128, 128]` f32 slab of that tree's values over the
+data tile, held in a VMEM bank and indexed on its leading axis, so a
+terminal costs no vector work at all. Its grid is (data_tiles,
+pop_tiles), the trees innermost, so each tile of X is read from HBM
+once per generation. Its moment epilogue reads the predictions back as
+`[pop_tile, moment_tile]` blocks and merges them in data order into the
+pop tile's moments, which an HBM buffer carries from one data tile to
+the next (so VMEM use does not grow with the population); its moments
+equal `gp_postfix_eval`'s at data_tile = moment_tile, bit for bit.
+
+The sublane kernels — `gp_postfix_eval` and the two dedup kernels —
+put 8 trees on the sublanes and follow these rules, because they are
+what Mosaic (the TPU kernel compiler) accepts:
 
   * every value the body computes on is 2-D f32 `[pop_tile, data_tile]`
     (trees on sublanes, data points on lanes) or a `[pop_tile, 1]` column
     that broadcasts across the data lanes;
-  * a genome slot — static (tree level sweep) or dynamic (postfix
-    instruction pointer) — is read as a lane-masked row sum (`_column`),
-    never as a lane slice or a lane-dynamic index;
+  * a genome slot (the postfix instruction pointer) is read as a
+    lane-masked row sum (`_column`), never as a lane slice or a
+    lane-dynamic index;
   * opcode dispatch is a `jnp.where` chain, never `jnp.select`;
   * terminal lookup is a one-hot matmul on the MXU against the
     terminal table (features stacked on constants), split into bf16
@@ -27,9 +40,9 @@ TPU kernel compiler) accepts:
   * per-row integer inputs (`lens`) are `[P, 1]` blocks, and the dedup
     row gather reads scalar-prefetched row ids from SMEM.
 
-Grid: (pop_tiles, data_tiles); the data dimension is innermost so each
-population tile's output block stays resident while fitness partials
-accumulate across data tiles.
+Their grid is (pop_tiles, data_tiles); the data dimension is innermost
+so each population tile's output block stays resident while fitness
+partials accumulate across data tiles.
 """
 from __future__ import annotations
 
@@ -138,7 +151,7 @@ def _apply_function_inline(op, lhs, rhs, fn_codes=None):
 
 def _accumulate_moments(out_ref, preds, y_ref, w_ref, *, kernel: str,
                         n_classes: int, precision: float):
-    """Fused moment epilogue shared by every kernel. Phase 1 of the
+    """Fused moment epilogue of the sublane kernels. Phase 1 of the
     two-pass protocol: the registered FitnessKernel's `moments` (pure
     jnp, so it traces inside the Pallas body) runs on this block's
     predictions, and the [Pb, M] partials accumulate across the data
@@ -159,32 +172,182 @@ def _accumulate_moments(out_ref, preds, y_ref, w_ref, *, kernel: str,
         out_ref[...] = kern.merge_moments(out_ref[...], partial, spec)
 
 
-def _eval_fitness_kernel(op_ref, arg_ref, table_ref, y_ref, w_ref, out_ref,
-                         *, n_features: int, kernel: str, n_classes: int,
-                         precision: float, fn_codes=None):
-    """One (pop_tile, data_tile) block: evaluate + reduce fitness partial."""
-    ops = op_ref[...].astype(jnp.float32)  # [Pb, N] small ints, exact in f32
-    args = arg_ref[...].astype(jnp.float32)
-    Pb, N = ops.shape
-    opc = [_column(ops, i) for i in range(N)]  # [Pb, 1] per slot
+def _postorder(n_nodes: int) -> list[int]:
+    """Heap slots in post-order: both subtrees before their root."""
+    def walk(s):
+        if s >= n_nodes:
+            return []
+        return walk(2 * s + 1) + walk(2 * s + 2) + [s]
+    return walk(0)
 
-    # ---- terminal values for every slot: ONE node-major lookup ------------
-    idx = jnp.concatenate(
-        [_terminal_index(opc[i], _column(args, i), n_features)
-         for i in range(N)], axis=0)  # [N*Pb, 1]
-    term = _lookup(idx, table_ref)  # [N*Pb, Db]; EMPTY slots read 0.0
 
-    # ---- bottom-up heap sweep, every slot a [Pb, Db] VMEM value ------------
-    vals = [None] * N
-    for i in range(N - 1, -1, -1):
-        node = term[i * Pb:(i + 1) * Pb]
-        if 2 * i + 2 < N:
-            fn = _apply_function_inline(opc[i], vals[2 * i + 1],
-                                        vals[2 * i + 2], fn_codes)
-            node = jnp.where(opc[i] >= _FN_BASE, fn, node)
-        vals[i] = node
-    _accumulate_moments(out_ref, vals[0], y_ref, w_ref, kernel=kernel,
-                        n_classes=n_classes, precision=precision)
+def _bank_rows(n_features: int, n_consts: int, n_nodes: int):
+    """int32[N] bank row that holds heap slot s's value when it is a
+    function (see `_eval_fitness_tree_kernel`): the root's row for slot
+    0, one buffer per (level, side) above the leaves, and the zero slab
+    at the leaves, where a function is malformed and reads as 0.0."""
+    zero = n_features + n_consts
+    depth = (n_nodes + 1).bit_length() - 2
+    rows = []
+    for s in range(n_nodes):
+        level = (s + 1).bit_length() - 1
+        rows.append(zero + 1 if s == 0 else
+                    zero + 2 * level + s % 2 if level < depth else zero)
+    return jnp.asarray(rows, jnp.int32)
+
+
+def bank_slabs(n_features: int, n_consts: int, max_depth: int) -> int:
+    """Slabs in the tree kernel's bank: F features, C constants, the
+    zero and root slabs, and one buffer per (level, side) of the levels
+    between the root and the leaves."""
+    return n_features + n_consts + 2 + 2 * max(max_depth - 1, 0)
+
+
+def _eval_fitness_tree_kernel(op_ref, arg_ref, row_ref, const_ref, x_hbm,
+                              y_ref, w_ref, out_hbm, bank_ref, root_ref,
+                              sched_ref, acc_ref, sem, *, moment_tile: int,
+                              n_chunks: int,
+                              chunk_rows: int, kernel: str, n_classes: int,
+                              precision: float, fn_codes):
+    """One (data_tile, pop_tile) block of the tree kernel.
+
+    Every value is a dense [R, 128] f32 slab of one tree over the data
+    tile (R = Db / 128 rows), held in the bank scratch `bank_ref`
+    [B, R, 128]: the F feature slabs (DMA'd from HBM once per data
+    tile), the C constant slabs, one zero slab (what an EMPTY slot
+    reads), the root's slab and one buffer per (level, side) of the heap
+    for function results (`row_ref`, from `_bank_rows`). A slot's value
+    is a bank row index, a scalar: a terminal costs no vector work at
+    all, and a function reads its children by index.
+
+    The trees of the pop tile run one after another. For each, a
+    branch-free scalar pass lists its function slots in post-order
+    (`sched_ref`); then each listed slot applies only its own operator,
+    chosen by a scalar branch on its SMEM opcode, slab chunk by chunk.
+    Post-order is what lets one buffer per (level, side) suffice: a left
+    child's value stays in its buffer while the right subtree runs,
+    which writes only the right side of the child level and deeper.
+
+    Each root slab is copied to `root_ref` [Pb · R, 128] (tree t at rows
+    [t·R, (t+1)·R)); the moment epilogue then reads the tile back as
+    [Pb, moment_tile] predictions, one 128-lane column of all Pb trees
+    per strided load, and merges the first `n_chunks` moment tiles of
+    the data in data order — the merge order of a sublane kernel at
+    data_tile = moment_tile, so the moments equal `gp_postfix_eval`'s
+    for the same trees bit for bit.
+
+    The pop tile's running [Pb, M] moments live in the first M lanes of
+    `acc_ref` [Pb, 128] while the block runs: read from the HBM output
+    `out_hbm` [P, 128] (a DMA that overlaps the trees) at every data
+    tile after the first, and written back at the end of the block, so
+    VMEM holds no buffer that grows with P. The rows are 128 lanes wide
+    because Mosaic slices an HBM row for a DMA only at that width."""
+    j, i = pl.program_id(0), pl.program_id(1)
+    Pb, N = op_ref.shape
+    F = x_hbm.shape[0]
+    C = const_ref.shape[0]
+    R = bank_ref.shape[1]
+    zero = F + C
+    codes = (list(fn_codes) if fn_codes is not None
+             else list(range(_FN_BASE, _FN_BASE + len(prim.FUNCTIONS))))
+    internal = [s for s in _postorder(N) if 2 * s + 1 < N]
+    rows = out_hbm.at[pl.ds(pl.multiple_of(i * Pb, Pb), Pb)]
+    read = pltpu.make_async_copy(rows, acc_ref, sem.at[0])
+
+    @pl.when(j != 0)
+    def _read_moments():
+        read.start()
+
+    @pl.when(i == 0)
+    def _load_tile():
+        pltpu.sync_copy(x_hbm.at[:, pl.ds(pl.multiple_of(j * R, 8), R), :],
+                        bank_ref.at[pl.ds(0, F)])
+        for c in range(C):
+            bank_ref[F + c] = jnp.full((R, 128), const_ref[c], jnp.float32)
+        bank_ref[zero] = jnp.zeros((R, 128), jnp.float32)
+
+    def slab_loop(body):
+        jax.lax.fori_loop(0, R // chunk_rows, lambda c, carry: (
+            body(pl.multiple_of(c * chunk_rows, chunk_rows)), carry)[1], 0)
+
+    def location(t, s):
+        """Bank row of slot s's value."""
+        o, a = op_ref[t, s], arg_ref[t, s]
+        return jnp.where(o == prim.FEATURE, jnp.clip(a, 0, F - 1),
+                         jnp.where(o == prim.CONST, F + jnp.clip(a, 0, C - 1),
+                                   jnp.where(o >= _FN_BASE, row_ref[s], zero)))
+
+    def apply(code, lhs, rhs, dst):
+        fn = prim.FUNCTIONS[code - _FN_BASE].fn
+
+        def body(r):
+            a = bank_ref[lhs, pl.ds(r, chunk_rows), :]
+            b = (bank_ref[rhs, pl.ds(r, chunk_rows), :]
+                 if prim.ARITY[code] == 2 else a)
+            bank_ref[dst, pl.ds(r, chunk_rows), :] = fn(a, b)
+        slab_loop(body)
+
+    def step(t, e, carry):
+        s = sched_ref[e]
+        o = op_ref[t, s]
+        kids = location(t, 2 * s + 1), location(t, 2 * s + 2)
+        for code in codes:
+            pl.when(o == code)(functools.partial(apply, code, *kids,
+                                                 row_ref[s]))
+        return carry
+
+    def tree(t, carry):
+        n = jnp.int32(0)
+        for s in internal:  # branch-free: every slot is written, functions kept
+            sched_ref[n] = jnp.int32(s)
+            n = n + (op_ref[t, s] >= _FN_BASE).astype(jnp.int32)
+        jax.lax.fori_loop(0, n, functools.partial(step, t), 0)
+        root = location(t, 0)
+        base = pl.multiple_of(t * R, 8)
+
+        def copy(r):
+            root_ref[pl.ds(base + r, chunk_rows), :] = (
+                bank_ref[root, pl.ds(r, chunk_rows), :])
+        slab_loop(copy)
+        return carry
+
+    jax.lax.fori_loop(0, Pb, tree, 0)
+
+    # ---- moment epilogue over [Pb, moment_tile] chunks, in data order ----
+    spec = fit.FitnessSpec(kernel, n_classes=n_classes, precision=precision)
+    kern = fit.get_kernel(kernel)
+    M = kern.n_moments
+    q = moment_tile // 128
+
+    @pl.when(j != 0)
+    def _wait_moments():
+        read.wait()
+
+    def chunk(k, carry):
+        r0 = k * q
+        preds = jnp.concatenate(
+            [root_ref[pl.ds(r0 + c, Pb, stride=R), :] for c in range(q)],
+            axis=1)  # [Pb, moment_tile]
+        y = jnp.concatenate([y_ref[pl.ds(r0 + c, 1), :] for c in range(q)],
+                            axis=1)
+        w = jnp.concatenate([w_ref[pl.ds(r0 + c, 1), :] for c in range(q)],
+                            axis=1)
+        partial = kern.moments(preds, y[0], w[0], spec)
+
+        @pl.when((j == 0) & (k == 0))
+        def _init():
+            acc_ref[:, :M] = partial
+
+        @pl.when((j != 0) | (k != 0))
+        def _acc():
+            acc_ref[:, :M] = kern.merge_moments(acc_ref[:, :M], partial,
+                                                spec)
+        return carry
+
+    per_tile = R // q
+    jax.lax.fori_loop(0, jnp.clip(n_chunks - j * per_tile, 0, per_tile),
+                      chunk, 0)
+    pltpu.sync_copy(acc_ref, rows)
 
 
 def _eval_fitness_postfix_kernel(op_ref, arg_ref, len_ref, table_ref,
@@ -194,8 +357,8 @@ def _eval_fitness_postfix_kernel(op_ref, arg_ref, len_ref, table_ref,
                                  fn_codes=None):
     """One (pop_tile, data_tile) block of the postfix stack interpreter.
 
-    Instead of the tree kernel's level sweep over all NODES slots, each
-    iteration executes ONE postfix instruction for the whole tile: a
+    Instead of a level sweep over all NODES slots, each iteration
+    executes ONE postfix instruction for the whole tile: a
     `fori_loop` whose trip count is the tile's max active length — with
     ops.py sorting rows by length, short-program tiles finish early,
     which is where the linear genome's speedup comes from.
@@ -257,7 +420,8 @@ def _eval_fitness_postfix_kernel(op_ref, arg_ref, len_ref, table_ref,
 
 def _data_specs(data_tile: int):
     """BlockSpecs of the [1, D] target and weight rows (the same for
-    every kernel; the index map ignores any scalar-prefetch refs)."""
+    every sublane kernel; the index map ignores any scalar-prefetch
+    refs)."""
     spec = pl.BlockSpec((1, data_tile), lambda i, j, *_: (0, j))
     return [spec, spec]
 
@@ -284,8 +448,9 @@ def eval_fitness_pallas_postfix(op, arg, lens, X, y, weight,
     lens:     int32[P]      active lengths (sort rows by length upstream so
                             tiles of short programs take short fori trips)
     X:        f32[F, D]     D % data_tile == 0
+    y, weight f32[D]        weight 1.0 on valid points, 0.0 on padding
     returns   f32[P, M]     accumulated weighted moments, same contract as
-                            eval_fitness_pallas
+                            eval_fitness_pallas_tree
 
     `stack_size` is TreeSpec.stack_size (= max_depth + 1), the operand-
     stack bound invariant P5 guarantees.
@@ -431,44 +596,81 @@ def eval_fitness_pallas_from_preds(preds, y, weight, *, kernel: str = "r",
     )(preds.astype(jnp.float32), _rows(y), _rows(weight))
 
 
-def eval_fitness_pallas(op, arg, X, y, weight, const_table, *,
-                        kernel: str = "r", n_classes: int = 3, precision: float = 1e-4,
-                        pop_tile: int = 8, data_tile: int = 1024,
-                        interpret: bool | None = None, fn_codes=None):
-    """Fused eval+moments over pre-padded inputs.
 
-    op, arg:  int32[P, N]   P % pop_tile == 0
-    X:        f32[F, D]     D % data_tile == 0
+
+def eval_fitness_pallas_tree(op, arg, X, y, weight, const_table, *,
+                             kernel: str = "r", n_classes: int = 3,
+                             precision: float = 1e-4, pop_tile: int = 8,
+                             data_tile: int = 1024, moment_tile: int = 1024,
+                             n_chunks: int | None = None,
+                             interpret: bool | None = None, fn_codes=None):
+    """Fused eval+moments of heap trees, one tree at a time with scalar
+    opcode dispatch over dense [data_tile / 128, 128] slabs of the data.
+
+    op, arg:  int32[P, N]   heap trees, P % pop_tile == 0
+    X:        f32[F, D]     D % data_tile == 0, data_tile % 1024 == 0
     y, weight f32[D]        weight is 1.0 on valid points, 0.0 on padding —
                             both the wrapper's tile padding AND any dataset
                             padding the caller threaded in (loader.pad_rows),
                             composed upstream in ops.fitness
-    returns   f32[P, M]     the kernel's fully-accumulated weighted moments
-                            (M = FitnessKernel.n_moments; for decomposable
-                            kernels M == 1 and [:, 0] is the fitness);
-                            finalize with FitnessKernel.reduce_moments
+    returns   f32[P, M]     the kernel's weighted moments (M =
+                            FitnessKernel.n_moments; for decomposable
+                            kernels M == 1 and [:, 0] is the fitness),
+                            merged moment tile after moment tile in data
+                            order (data_tile % moment_tile == 0) over the
+                            first `n_chunks` moment tiles (default: all),
+                            so a caller can pad D to whole data tiles
+                            without merging the extra moment tiles; the
+                            bits `eval_fitness_pallas_postfix` gives the
+                            same trees at data_tile = moment_tile.
+                            Finalize with FitnessKernel.reduce_moments.
+
+    Grid: (data_tiles, pop_tiles), the trees innermost, so X's tile is
+    read from HBM once (at the first pop tile) and serves every tree;
+    each block carries its pop tile's moments from the previous data
+    tile through a [P, 128] HBM buffer, so any P fits VMEM.
     """
     P, N = op.shape
     F, D = X.shape
-    assert P % pop_tile == 0 and D % data_tile == 0, (P, D, pop_tile, data_tile)
-    table = terminal_table(X, const_table)
-    K = table.shape[1]
-    out_spec, out_shape = _moment_out(P, pop_tile, kernel)
+    R = data_tile // 128
+    assert (P % pop_tile == 0 and D % data_tile == 0 and R % 8 == 0
+            and data_tile % moment_tile == 0 and moment_tile % 128 == 0), (
+        P, D, pop_tile, data_tile, moment_tile)
+    C = const_table.shape[0]
+    depth = (N + 1).bit_length() - 2
+    chunk_rows = next(c for c in (32, 16, 8) if R % c == 0)
+    M = fit.get_kernel(kernel).n_moments
+    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
+    slab = pl.BlockSpec((R, 128), lambda j, i: (j, 0))
     body = functools.partial(
-        _eval_fitness_kernel, n_features=F,
-        kernel=kernel, n_classes=n_classes, precision=precision,
-        fn_codes=fn_codes)
+        _eval_fitness_tree_kernel, moment_tile=moment_tile,
+        n_chunks=D // moment_tile if n_chunks is None else n_chunks,
+        chunk_rows=chunk_rows, kernel=kernel, n_classes=n_classes,
+        precision=precision, fn_codes=fn_codes)
     return pl.pallas_call(
         body,
         name="gp_tree_eval",
-        grid=(P // pop_tile, D // data_tile),
+        grid=(D // data_tile, P // pop_tile),
         in_specs=[
-            pl.BlockSpec((pop_tile, N), lambda i, j: (i, 0)),
-            pl.BlockSpec((pop_tile, N), lambda i, j: (i, 0)),
-            pl.BlockSpec((TABLE_PARTS, K, data_tile), lambda i, j: (0, 0, j)),
-            *_data_specs(data_tile),
+            smem((pop_tile, N), lambda j, i: (i, 0)),
+            smem((pop_tile, N), lambda j, i: (i, 0)),
+            smem(), smem(),
+            pl.BlockSpec(memory_space=pl.ANY),
+            slab, slab,
         ],
-        out_specs=out_spec,
-        out_shape=out_shape,
+        out_specs=pl.BlockSpec(memory_space=pltpu.HBM),
+        out_shape=jax.ShapeDtypeStruct((P, 128), jnp.float32),
+        scratch_shapes=[
+            pltpu.VMEM((bank_slabs(F, C, depth), R, 128), jnp.float32),
+            pltpu.VMEM((pop_tile * R, 128), jnp.float32),
+            pltpu.SMEM((max(N // 2, 1),), jnp.int32),
+            pltpu.VMEM((pop_tile, 128), jnp.float32),
+            pltpu.SemaphoreType.DMA((1,))],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret_mode(interpret),
-    )(op, arg, table, _rows(y), _rows(weight))
+    )(op.astype(jnp.int32), arg.astype(jnp.int32),
+      _bank_rows(F, C, N), const_table.astype(jnp.float32),
+      X.astype(jnp.float32).reshape(F, D // 128, 128),
+      y.astype(jnp.float32).reshape(-1, 128),
+      weight.astype(jnp.float32).reshape(-1, 128))[:, :M]

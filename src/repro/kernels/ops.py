@@ -14,6 +14,7 @@ can flip implementations with one flag.
 """
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
@@ -23,44 +24,78 @@ from repro.core import eval as _eval
 from repro.core.fitness import FitnessSpec
 from repro.core.trees import TreeSpec
 from repro.kernels import ref as _ref
-from repro.kernels.gp_eval import (TABLE_PARTS, eval_fitness_pallas,
+from repro.kernels.gp_eval import (TABLE_PARTS, bank_slabs,
                                    eval_fitness_pallas_from_preds,
                                    eval_fitness_pallas_from_subtrees,
-                                   eval_fitness_pallas_postfix)
+                                   eval_fitness_pallas_postfix,
+                                   eval_fitness_pallas_tree)
 
 _VMEM_BUDGET = 12 * 2**20  # bytes; leave headroom under ~16 MB/core
 
 
-def pick_tiles(n_terms: int, n_nodes: int, pop: int, data: int,
-               pop_tile: int = 8, data_tile: int = 1024):
-    """Choose (pop_tile, data_tile) for the tree kernel under the VMEM budget.
-
-    VMEM per block, with K = n_terms = features + constants (the rows of
-    gp_eval.terminal_table):
-        table:  2 buffers · 4 bf16 parts · K · Db · 2  = 16·K·Db
-        slots:  ≤ 4 · Pb · (N+1) · Db · 4  (the lookup's four products,
-                every slot's terminal value and the sweep's live values)
-        onehot: Pb · N · K · 2       (bf16, charged at 4 bytes)
-    Mosaic reports 29.1 MiB for F=9 at Db=4096 (modelled 33.1 MiB) and
-    21.6 MiB for F=1,373 at Db=1024 (modelled 32.2 MiB).
-    """
+def _moment_tile(n_terms: int, n_nodes: int, pop_tile: int,
+                 data_tile: int) -> int:
+    """Rows each partial-moment merge of the tree fitness covers. This
+    is the tree fitness's rounding contract: the moments of a tree are
+    merged over consecutive blocks of this many rows, in data order, so
+    a fitness keeps its bits from release to release. The rule is
+    `data_tile` halved, down to 128 rows, while 4·(4·K·Db +
+    4·Pb·(N+1)·Db + Pb·N·K) bytes exceed 12 MiB (K = n_terms = features
+    + constants, N = n_nodes): 1,024 rows at KAT-7's 9 features, 256 at
+    LIGO's 1,373."""
     Db = data_tile
-
-    def vmem(Db):
-        return 4 * (TABLE_PARTS * n_terms * Db
-                    + 4 * pop_tile * (n_nodes + 1) * Db
-                    + pop_tile * n_nodes * n_terms)
-
-    while Db > 128 and vmem(Db) > _VMEM_BUDGET:
+    while Db > 128 and 4 * (TABLE_PARTS * n_terms * Db
+                            + 4 * pop_tile * (n_nodes + 1) * Db
+                            + pop_tile * n_nodes * n_terms) > _VMEM_BUDGET:
         Db //= 2
-    return pop_tile, Db
+    return Db
+
+
+def _tree_vmem(n_features: int, n_consts: int, max_depth: int,
+               pop_tile: int, data_tile: int) -> int:
+    """VMEM bytes of the tree kernel, in f32 slabs of data_tile values:
+    the bank (`gp_eval.bank_slabs`), Pb root slabs, y and w
+    double-buffered. The population adds nothing: each block carries
+    only its own pop tile's moments."""
+    slabs = bank_slabs(n_features, n_consts, max_depth) + pop_tile + 4
+    return 4 * slabs * data_tile
+
+
+def pick_tiles(n_features: int, n_consts: int, max_depth: int, data: int,
+               pop_tile: int = 8, data_tile: int = 1024):
+    """(pop_tile, data_tile, moment_tile) of the tree kernel.
+
+    moment_tile is `_moment_tile`'s, from the callers' `data_tile` down.
+    The kernel's data_tile is the fewest data tiles the VMEM budget
+    allows, balanced over `data` rows padded to whole moment tiles, each
+    a multiple of 1,024 rows and of the moment tile: 2 tiles of 45,056
+    rows for KAT-7's 90,000 × 9. It is chosen from the shapes alone.
+
+    Raises ValueError when not even one such tile fits: the bank holds
+    every feature, so that is past 3,042 features at depth 5 with 8
+    constants, whatever the population.
+    """
+    n_nodes = 2 ** (max_depth + 1) - 1
+    moment_tile = _moment_tile(n_features + n_consts, n_nodes, pop_tile,
+                               data_tile)
+    unit = math.lcm(1024, moment_tile)
+    vmem = partial(_tree_vmem, n_features, n_consts, max_depth, pop_tile)
+    most = (_VMEM_BUDGET - vmem(0)) // (vmem(1) - vmem(0)) // unit * unit
+    if most < unit:
+        raise ValueError(
+            f"{n_features} features do not fit the tree kernel's VMEM "
+            f"budget at {unit:,} rows; evaluate with the jnp backend "
+            f"(backend='jnp')")
+    rows = -(-data // moment_tile) * moment_tile
+    n = -(-rows // most)
+    return pop_tile, -(-rows // (n * unit)) * unit, moment_tile
 
 
 def pick_tiles_postfix(n_terms: int, stack_size: int, pop: int, data: int,
                        pop_tile: int = 8, data_tile: int = 1024,
                        dedup_rows: int = 0):
     """Tile pick for the postfix stack kernel. The carried state is S
-    [Pb, Db] stack slots (S = max_depth + 1), ~S/N of the tree kernel's
+    [Pb, Db] stack slots (S = max_depth + 1), ~S/N of a level sweep's
     node-resident buffers, so the data tile can grow under the same VMEM
     budget — fewer, larger grid blocks amortize the per-instruction loop.
 
@@ -128,7 +163,9 @@ def _moments_padded(op, arg, X, y, const_table, tree_spec: TreeSpec,
             K, tree_spec.stack_size, pop_tile, data_tile,
             dedup_rows=cap) <= _VMEM_BUDGET)
     else:
-        pop_tile, data_tile = pick_tiles(K, N, P, D, pop_tile, data_tile)
+        pop_tile, data_tile, moment_tile = pick_tiles(
+            F, const_table.shape[0], tree_spec.max_depth, D, pop_tile,
+            data_tile)
 
     pad_p = (-P) % pop_tile
     pad_d = (-D) % data_tile
@@ -184,10 +221,11 @@ def _moments_padded(op, arg, X, y, const_table, tree_spec: TreeSpec,
 
             return jax.lax.cond(plan.overflow, _plain, _dedup)[:P]
         return _plain()[:P]
-    out = eval_fitness_pallas(
+    out = eval_fitness_pallas_tree(
         op, arg, X, y, weight, const_table, kernel=fit_spec.kernel,
         n_classes=fit_spec.n_classes, precision=fit_spec.precision,
-        pop_tile=pop_tile, data_tile=data_tile, interpret=interpret,
+        pop_tile=pop_tile, data_tile=data_tile, moment_tile=moment_tile,
+        n_chunks=-(-D // moment_tile), interpret=interpret,
         fn_codes=fn_codes)
     return out[:P]
 

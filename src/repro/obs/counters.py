@@ -40,20 +40,28 @@ Columns (index into the trailing axis; see docs/observability.md):
                    overflowed, which is how a too-small cap shows up
                    in telemetry) — saved / (saved + unique) is the
                    generation's duplicate rate
+    NODE_EVALS     active (non-EMPTY) genome slots of the rows TREE_EVALS
+                   counts, cache-served rows excluded, terminals
+                   included: an upper bound on the tree kernel's
+                   per-slot work (only a function slot does vector
+                   work; a terminal is a bank row index)
 
 Mesh notes: the sharded step bodies carry the elite cache through
 untouched (it is host/single-device machinery), so CACHE_* columns are
 0 on a mesh; the dedup columns are likewise 0 on a mesh and in the
 tenant batch (re-running the signature sort per shard/slot purely for
-telemetry would double the plan cost); every other column is computed
-from replicated quantities and is identical on all shards.
+telemetry would double the plan cost); NODE_EVALS is 0 on a mesh too
+(where a shard holds part of the population, summing it would add a
+collective to every generation); every other column is computed from
+replicated quantities and is identical on all shards.
 """
 from __future__ import annotations
 
 COUNTERS = ("cache_hits", "cache_queries", "frozen", "migrations",
-            "tree_evals", "subtree_evals_saved", "unique_subtrees")
+            "tree_evals", "subtree_evals_saved", "unique_subtrees",
+            "node_evals")
 (CACHE_HITS, CACHE_QUERIES, FROZEN, MIGRATIONS, TREE_EVALS,
- SUBTREE_EVALS_SAVED, UNIQUE_SUBTREES) = range(7)
+ SUBTREE_EVALS_SAVED, UNIQUE_SUBTREES, NODE_EVALS) = range(8)
 N_COUNTERS = len(COUNTERS)
 
 

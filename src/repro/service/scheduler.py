@@ -110,7 +110,8 @@ class GPService:
         self.stats = {"blocks": 0, "admissions": 0, "evictions": 0,
                       "restarts": 0, "compiles": 0, "block_s_ema": None,
                       "stragglers": [], "cache_hits": 0, "cache_queries": 0,
-                      "cache_hit_rate": 0.0, "frozen": 0, "tree_evals": 0}
+                      "cache_hit_rate": 0.0, "frozen": 0, "tree_evals": 0,
+                      "node_evals": 0}
         # observability (repro.obs): host-side only — the compiled tenant
         # block is identical with or without a tracer/metrics sink (the
         # counter stream is unconditional), so the no-recompile guarantee

@@ -49,7 +49,7 @@ def test_block_bitwise_identical_to_stepwise():
                                       err_msg=f"GPState.{name} diverged")
     assert hist.shape == (K,)
     assert float(hist[-1]) == float(s_step.best_fitness)
-    assert counters.shape == (K, 7)  # telemetry stream rides the same scan
+    assert counters.shape == (K, 8)  # telemetry stream rides the same scan
 
 
 def test_block_early_stop_freezes_on_device():
@@ -271,6 +271,8 @@ _SUBPROCESS_MESH_BLOCKS = textwrap.dedent("""
                                       err_msg="GPState." + name)
     assert hist.shape == (6,)
     assert float(np.asarray(hist)[-1]) == float(s_step.best_fitness)
+    # the cache, dedup and node_evals columns are 0 on a mesh
+    assert (np.asarray(counters)[:, [0, 1, 5, 6, 7]] == 0).all(), counters
 
     # acceptance: odd rows shard on data=2 — padded, masked, and the
     # evaluated fitness matches the unpadded single-device computation

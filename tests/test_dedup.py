@@ -344,7 +344,7 @@ def test_evolve_trajectory_dedup_bitwise(islands, cap):
 def test_tenant_block_dedup_bitwise():
     """The multi-tenant batch: a dedup="exact" block must replay the
     dedup="off" block bit for bit (per-slot plans, frozen slots, the
-    elite cache and the 7-column counter stream all in play)."""
+    elite cache and the 8-column counter stream all in play)."""
     spec = TreeSpec(max_depth=4, n_features=3, n_consts=8, genome="postfix")
     I, P, Dc = 3, 16, 64
     state = eng.empty_tenant_state(I, P, spec, elitism=1)
@@ -376,7 +376,7 @@ def test_tenant_block_dedup_bitwise():
                                       err_msg=name)
     np.testing.assert_array_equal(np.asarray(h_off), np.asarray(h_on))
     assert np.asarray(c_on).shape == np.asarray(c_off).shape
-    assert np.asarray(c_on).shape[1] == 7
+    assert np.asarray(c_on).shape[1] == 8
 
 
 def test_counter_stream_reports_dedup_columns():
@@ -393,7 +393,7 @@ def test_counter_stream_reports_dedup_columns():
     _, _, ctr = eng.evolve_block(cfg, init_state(cfg, jax.random.PRNGKey(0)),
                                  X, y, None, n_steps=4)
     ctr = np.asarray(ctr)
-    assert ctr.shape == (4, tc.N_COUNTERS) == (4, 7)
+    assert ctr.shape == (4, tc.N_COUNTERS) == (4, 8)
     assert (ctr[:, tc.UNIQUE_SUBTREES] > 0).all()
     # 32 trees over 3 features + 8 consts: pigeonhole guarantees shared
     # terminal subtrees every generation
@@ -402,7 +402,8 @@ def test_counter_stream_reports_dedup_columns():
     _, _, c0 = eng.evolve_block(cfg_off,
                                 init_state(cfg_off, jax.random.PRNGKey(0)),
                                 X, y, None, n_steps=4)
-    assert (np.asarray(c0)[:, tc.SUBTREE_EVALS_SAVED:] == 0).all()
+    assert (np.asarray(c0)[:, [tc.SUBTREE_EVALS_SAVED,
+                               tc.UNIQUE_SUBTREES]] == 0).all()
 
 
 # --- tier 2: semantic probe-fingerprint cache --------------------------------

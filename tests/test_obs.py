@@ -17,6 +17,7 @@ import json
 import os
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -150,6 +151,125 @@ def test_counter_stream_accounts_evaluations():
     assert st["tree_evals"] == 12 * 16 - st["cache_hits"] * 1  # elitism=1
     assert st["cache_hit_rate"] == pytest.approx(
         st["cache_hits"] / st["cache_queries"])
+
+
+@pytest.mark.parametrize("islands", [1, 3])
+def test_node_evals_counts_active_slots(islands):
+    """NODE_EVALS of each generation of a block is the host's count of
+    the non-EMPTY slots of the rows that generation scored: the PRE-step
+    population, less the elite rows the cache served on a hit."""
+    X_rows, y, _ = kepler()
+    s = GPSession(pop_size=16, generations=6, kernel="r", backend="jnp",
+                  islands=islands, migrate_every=2, migrate_k=2)
+    s.ingest(X_rows, y)
+    s.init(key=jax.random.PRNGKey(0))
+    cfg, K, init = s.config, 6, jax.device_get(s.state)
+    want, state = [], init
+    for _ in range(K):
+        host = jax.device_get(state)
+        op, E = np.asarray(host.op), host.cache_op.shape[-2]
+        hit = (np.array_equal(op[..., :E, :], host.cache_op)
+               and np.array_equal(np.asarray(host.arg)[..., :E, :],
+                                  host.cache_arg))
+        want.append(int((op != 0).sum() - hit * (op[..., :E, :] != 0).sum()))
+        state = engine.evolve_step(cfg, jax.tree.map(jnp.asarray, host),
+                                   s._X, s._y, s._weight)
+    _, _, rows = engine.evolve_block(cfg, jax.tree.map(jnp.asarray, init),
+                                     s._X, s._y, s._weight, n_steps=K)
+    rows = np.asarray(rows)
+    assert rows[:, counters.NODE_EVALS].tolist() == want
+    assert counters.totals(rows)["node_evals"] == sum(want)
+    s.adopt_state(init).evolve(K)
+    assert s.stats["node_evals"] == sum(want)
+
+
+def test_node_evals_counts_active_slots_tenant_block():
+    """The tenant block's NODE_EVALS is the host's count over the slots
+    still evolving: each one's non-EMPTY slots, less its cache-served
+    elite rows on a hit. Slot 1's budget runs out after 2 generations,
+    so its frozen steps count nothing."""
+    from repro.core.trees import TreeSpec
+
+    spec = TreeSpec(max_depth=4, n_features=3, n_consts=8)
+    I, P, Dc, K = 3, 16, 64, 5
+    state = engine.empty_tenant_state(I, P, spec, elitism=1)
+    for i in range(I):
+        sub = engine.init_tenant_slot(jax.random.PRNGKey(i), P, spec,
+                                      elitism=1)
+        state = jax.tree.map(lambda b, s, i=i: b.at[i].set(s), state, sub)
+    r = np.random.RandomState(3)
+    X = jnp.asarray(r.randn(I, 3, Dc).astype(np.float32))
+    y = jnp.asarray(r.randn(I, Dc).astype(np.float32))
+    w = jnp.ones((I, Dc), jnp.float32)
+    params = engine.TenantParams(
+        probs=jnp.tile(jnp.asarray([[0.1, 0.1, 0.1, 0.7]], jnp.float32),
+                       (I, 1)),
+        tourn=jnp.full((I,), 4, jnp.int32),
+        point_rate=jnp.full((I,), 0.1, jnp.float32),
+        kernel_id=jnp.zeros((I,), jnp.int32),
+        n_classes=jnp.full((I,), 3.0, jnp.float32),
+        precision=jnp.full((I,), 1e-4, jnp.float32),
+        stop=jnp.full((I,), -jnp.inf, jnp.float32),
+        budget=jnp.asarray([K, 2, K], jnp.int32))
+    step = jax.jit(engine.build_tenant_block(spec, ("r",), 6, 1, 1))
+    want, st = [], state
+    for _ in range(K):
+        host = jax.device_get(st)
+        op, E = np.asarray(host.op), host.cache_op.shape[1]
+        active = ((np.asarray(host.gens_done) < np.asarray(params.budget))
+                  & ~(np.asarray(host.best_fitness) <= -np.inf))
+        n = 0
+        for i in np.flatnonzero(active):
+            hit = (np.array_equal(op[i, :E], host.cache_op[i])
+                   and np.array_equal(np.asarray(host.arg)[i, :E],
+                                      host.cache_arg[i]))
+            n += int((op[i] != 0).sum() - hit * (op[i, :E] != 0).sum())
+        want.append(n)
+        st, _, _ = step(st, X, y, w, params)
+    _, _, rows = jax.jit(engine.build_tenant_block(spec, ("r",), 6, 1, K))(
+        state, X, y, w, params)
+    rows = np.asarray(rows)
+    assert rows[:, counters.NODE_EVALS].tolist() == want
+    assert rows[2:, counters.FROZEN].tolist() == [1] * (K - 2)
+    assert want[-1] < want[0]  # the frozen slot's trees are not counted
+
+
+_SUBPROCESS_MESH_NODE_EVALS = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, numpy as np
+from repro.data.datasets import iris
+from repro.gp import GPSession, MeshTopology
+from repro.obs import counters
+
+X_rows, y, _ = iris()
+for topo in (MeshTopology(data=2, pod=2), MeshTopology(data=4)):
+    s = GPSession(pop_size=16, generations=4, kernel="r", backend="jnp",
+                  topology=topo)
+    s.fit(X_rows, y, key=jax.random.PRNGKey(0))
+    assert s.stats["tree_evals"] > 0, s.stats
+    assert s.stats["node_evals"] == 0, s.stats
+solo = GPSession(pop_size=16, generations=4, kernel="r", backend="jnp")
+solo.fit(X_rows, y, key=jax.random.PRNGKey(0))
+assert solo.stats["node_evals"] > 0, solo.stats
+print("MESH_NODE_EVALS_OK")
+"""
+
+
+def test_node_evals_zero_on_a_mesh():
+    """On a CPU multi-device mesh NODE_EVALS reads 0, as the cache and
+    dedup columns do: a shard holds part of the population, and the
+    counter row adds no collective to sum it."""
+    import subprocess
+    import sys
+
+    env = dict(os.environ, PYTHONPATH=os.path.join(
+        os.path.dirname(__file__), "..", "src"))
+    env.pop("XLA_FLAGS", None)
+    r = subprocess.run([sys.executable, "-c", _SUBPROCESS_MESH_NODE_EVALS],
+                       env=env, capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "MESH_NODE_EVALS_OK" in r.stdout
 
 
 def test_frozen_steps_counted_not_evaluated():
@@ -427,12 +547,13 @@ def test_block_monitor_routes_all_timing():
 
 
 def test_counter_helpers():
-    rows = np.array([[1, 1, 0, 0, 16, 40, 8], [0, 1, 1, 3, 15, 20, 9]],
-                    np.int32)
+    rows = np.array([[1, 1, 0, 0, 16, 40, 8, 250],
+                     [0, 1, 1, 3, 15, 20, 9, 230]], np.int32)
     tot = counters.totals(rows)
     assert tot == {"cache_hits": 1, "cache_queries": 2, "frozen": 1,
                    "migrations": 3, "tree_evals": 31,
-                   "subtree_evals_saved": 60, "unique_subtrees": 17}
+                   "subtree_evals_saved": 60, "unique_subtrees": 17,
+                   "node_evals": 480}
     assert counters.hit_rate(tot) == pytest.approx(0.5)
     assert counters.hit_rate({"cache_hits": 0, "cache_queries": 0}) == 0.0
 

@@ -6,7 +6,8 @@ slice, a lane-dynamic index, an unsupported gather or a block past the
 VMEM limit. These tests compile each kernel that `kernels/ops.py` can
 reach — the tree kernel, the postfix kernel and both dedup kernels — at
 the eval cell's shapes (P=1024, N=63, D=32,768, KAT-7's F=9, plus
-LIGO's F=1,373), at the tiles the pickers choose, for a chip that is
+LIGO's F=1,373, and the tree kernel at the KAT-7 benchmark cell's
+P=100, D=90,000), at the tiles the pickers choose, for a chip that is
 described and not attached. Nothing runs; a compile that passes is not a
 chip run.
 
@@ -91,8 +92,9 @@ def test_postfix_kernel_compiles(one_chip, kernel):
 
 @pytest.mark.parametrize("genome", ["tree", "postfix"])
 def test_ligo_width_compiles(one_chip, genome):
-    """F=1,373: the 1,381-row terminal table makes the pickers shrink the
-    data tile; the compiler must accept the block they choose."""
+    """F=1,373: the 1,381-row terminal table (postfix) or feature bank
+    (tree) makes the pickers shrink the data tile; the compiler must
+    accept the block they choose."""
     assert _kernels(_compile_fitness(one_chip, genome, "c", LIGO_F)) == 1
 
 
@@ -157,3 +159,27 @@ def test_tree_fit_block_carries_scopes_and_kernel_name(one_chip, monkeypatch):
         # a name-stack component of some op's location
         assert re.search(rf'["/]{re.escape(scope)}[/"]', text), scope
     assert 'kernel_name = "gp_tree_eval"' in text
+
+
+@pytest.mark.parametrize("F,pop,rows,tile", [
+    (KAT7_F, 100, 90_000, 45_056),   # the kat7-90k.tree-fit cell
+    (KAT7_F, P, D, 32_768),
+    (LIGO_F, P, D, 2_048),
+    (KAT7_F, 100_000, 90_000, 45_056)])
+def test_tree_kernel_compiles_at_picked_tile(one_chip, F, pop, rows, tile):
+    """gp_tree_eval through ops.fitness at the data tile its picker
+    chooses: the KAT-7 cell's two tiles of 45,056 rows, one tile at
+    P=1,024, LIGO's width, where 1,373 feature slabs in the bank leave
+    a 2,048-row tile, and 100,000 trees, whose moments VMEM never holds
+    all at once."""
+    assert kops.pick_tiles(F, C, DEPTH, rows)[1] == tile
+    spec = TreeSpec(max_depth=DEPTH, n_features=F, n_consts=C,
+                    fn_set=prim.CLASSIFY_SET)
+    N = spec.num_nodes
+    lowered = kops.fitness.lower(
+        _sds(one_chip, (pop, N), jnp.int32), _sds(one_chip, (pop, N), jnp.int32),
+        _sds(one_chip, (F, rows), jnp.float32),
+        _sds(one_chip, (rows,), jnp.float32), _sds(one_chip, (C,), jnp.float32),
+        spec, FitnessSpec("c", n_classes=2), interpret=False)
+    assert 'kernel_name = "gp_tree_eval"' in lowered.as_text()
+    assert _kernels(lowered.compile().as_text()) == 1
