@@ -26,22 +26,17 @@ from __future__ import annotations
 import numpy as np
 
 import reference
-from harness import datagen
+from harness import cells, datagen
 
 
 def _session(cell):
-    from repro.core import primitives as prim
+    """A session of the configuration's `gp` keys, each as the program
+    names it, and the mix's `session` options."""
     from repro.gp import GPSession
 
-    gp, sess_kw = cell.config["gp"], dict(cell.traffic.get("session", {}))
-    sess = GPSession(
-        pop_size=gp["pop_size"], tourn_size=gp["tourn_size"],
-        generations=gp["generations"], max_depth=gp["max_depth"],
-        n_consts=gp["n_consts"], kernel=gp["kernel"],
-        n_classes=gp.get("n_classes", 2),
-        fn_set=prim.FunctionSet.make(tuple(gp["fn_set"])),
-        backend=gp["backend"], **sess_kw)
-    want = gp.get("require_backend")
+    gp = dict(cell.config["gp"])
+    want = gp.pop("require_backend", None)
+    sess = GPSession(**gp, **cell.traffic.get("session", {}))
     if want and sess.backend != want:
         raise RuntimeError(f"backend {gp['backend']!r} resolved to "
                            f"{sess.backend!r}, and this cell needs {want!r}")
@@ -55,7 +50,7 @@ def run(cell, seed: int, seconds: float, window, control: bool = False):
     ds = cell.config["dataset"]
     gens = int(cell.config["gp"]["generations"])
     data_seed, key_seed = datagen.seeds(seed, 2)
-    X_rows, y = datagen.BY_NAME[ds["generator"]](ds["rows"], data_seed)
+    X_rows, y = cells.make_dataset(ds, data_seed, cell.root)
     sess = _session(cell)
     sess.ingest(X_rows, y)
     base = jax.random.PRNGKey(key_seed)
@@ -123,7 +118,7 @@ def _check(host, X_rows, y, cell, spec, gens, control):
 
     gp = cell.config["gp"]
     consts = np.asarray(spec.const_table())
-    n_classes = gp.get("n_classes", 2)
+    n_classes = gp["n_classes"]
 
     def score(text, dtype="float32"):
         return reference.score(text, X_rows, y, gp["kernel"], n_classes,

@@ -4,12 +4,13 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 A cell is one entry of `workloads` in BENCHMARK.json: a configuration
-(`bench/configs/<config>.json`) under a traffic mix
-(`bench/traffic/<traffic>.json`, whose `driver` names the loop in
-`bench/harness/` that drives it). The run sets up (data from the seed,
-compile or cache load, one warm pass of the cell's shapes), measures for
-`--seconds`, then checks what the window produced against the plain
-reference in `bench/reference/`.
+(`bench/configs/<config>.json`, whose `dataset` names its generator in
+`bench/datasets/`) under a traffic mix (`bench/traffic/<traffic>.json`,
+whose `driver` names the loop `bench/harness/<driver>.py` that drives
+it). The run sets up (data from the seed, compile or cache load, one
+warm pass of the cell's shapes), measures for `--seconds`, then checks
+what the window produced against the plain reference in
+`bench/reference/`.
 
 With `--trace 0` the result carries the cell's end-to-end metrics; with
 `--trace 1` the window runs under the profiler and the result carries
@@ -46,12 +47,6 @@ def log(msg: str):
     print(msg, file=sys.stderr, flush=True)
 
 
-def _drivers():
-    from harness import fits
-
-    return {"fits": fits.run}
-
-
 def _per_layer(cell, rec, window, peaks) -> dict:
     from harness.cells import metric_reader
 
@@ -60,7 +55,7 @@ def _per_layer(cell, rec, window, peaks) -> dict:
            "peaks": peaks, "chips": cell.chips}
     out = {}
     for m in cell.per_layer:
-        value = metric_reader(m["name"])(ctx)
+        value = metric_reader(m["name"], cell.root)(ctx)
         if value is not None:
             out[m["name"]] = {"value": value, "unit": m["unit"]}
     return out
@@ -75,10 +70,11 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         raise SystemExit(f"bench: no repository source at {ROOT / 'src'}")
     if str(ROOT / "src") not in sys.path:
         sys.path.insert(0, str(ROOT / "src"))
-    from harness.cells import load_cell, peaks_for
+    from harness.cells import driver, load_cell, peaks_for
     from harness.window import Window
 
     cell = cell if cell is not None else load_cell(workload)
+    drive = driver(cell.traffic["driver"], cell.root)
     import jax
 
     devices = jax.devices()
@@ -97,8 +93,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     probe = CacheProbe()
     window = Window(T_START, trace, used,
                     float(cell.traffic.get("trace_seconds", 5.0)))
-    rec = _drivers()[cell.traffic["driver"]](cell, seed, seconds, window,
-                                             control=control)
+    rec = drive(cell, seed, seconds, window, control=control)
     checks = rec["checks"]
     correct = all(value <= limit for _, value, limit in checks)
     result = {"correct": correct, "attempted": rec["attempted"],

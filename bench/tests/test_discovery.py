@@ -5,7 +5,8 @@ import re
 
 import pytest
 
-from harness.cells import BENCH, ROOT, load_cell, metric_reader, peaks_for
+from harness.cells import (BENCH, ROOT, driver, load_cell, metric_reader,
+                           peaks_for)
 
 BENCHMARK = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -15,7 +16,10 @@ WORKLOADS = [w["name"] for w in BENCHMARK["workloads"]]
 @pytest.mark.parametrize("workload", WORKLOADS)
 def test_cell_parts_found_by_name(workload):
     cell = load_cell(workload)
-    assert cell.traffic["driver"] == "fits"
+    assert (BENCH / "harness" / f"{cell.traffic['driver']}.py").is_file()
+    assert callable(driver(cell.traffic["driver"]))
+    generator = cell.config["dataset"]["generator"]
+    assert (BENCH / "datasets" / f"{generator}.py").is_file()
     assert {m["name"] for m in cell.end_to_end} >= {"setup_s"}
     assert len(cell.end_to_end) >= 2 and cell.per_layer
     reported = {m["name"] for m in cell.end_to_end}
